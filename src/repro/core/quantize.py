@@ -164,12 +164,16 @@ def unpack_codes(packed: jax.Array, group_size: int = DEFAULT_GROUP_SIZE) -> jax
     return jnp.concatenate([lo, hi], axis=-2).reshape(*lead, ci2 * 2, co)
 
 
+@functools.partial(jax.jit, static_argnames=("group_size", "dtype"))
 def quantize(
     w: jax.Array,
     group_size: int = DEFAULT_GROUP_SIZE,
     dtype: jnp.dtype | None = None,
 ) -> QuantizedTensor:
-    """Group-wise asymmetric 4-bit RTN quantization of ``W[Ci, Co]``."""
+    """Group-wise asymmetric 4-bit RTN quantization of ``W[Ci, Co]``.
+
+    Jitted so the f32 working copies fuse away: at full width a stacked
+    weight is gigabytes in f32, and op-by-op they would pile up in HBM."""
     _check_nd(w)
     dtype = dtype or w.dtype
     scales, zeros = compute_qparams(w, group_size)
@@ -233,11 +237,14 @@ def quantize_acts_per_token(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     eligibility metric measures.
     """
     xf = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
-    scales = jnp.maximum(amax, 1e-8) / ACT_QMAX
-    codes = jnp.clip(jnp.round(xf / scales), -ACT_QMAX, ACT_QMAX).astype(
-        jnp.int8
-    )
+    amax = jnp.maximum(jnp.max(jnp.abs(xf), axis=-1, keepdims=True), 1e-8)
+    scales = amax / ACT_QMAX
+    # multiply by an explicit per-row reciprocal, never ``xf / scales``: XLA
+    # rewrites ``x / (a / c)`` to ``x * c / a`` inside a jit but not when run
+    # op by op, and the two round a code that sits on a .5 tie differently
+    # — the jitted kernel wrapper and the eager oracle must agree exactly
+    codes = jnp.clip(jnp.round(xf * (ACT_QMAX / amax)), -ACT_QMAX,
+                     ACT_QMAX).astype(jnp.int8)
     return codes, scales
 
 

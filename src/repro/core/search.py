@@ -14,6 +14,7 @@ search is ~5× faster than AWQ's).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -33,6 +34,16 @@ class SearchResult:
     losses: Dict[float, float]          # full grid → loss curve (paper Tab. 4)
 
 
+@functools.partial(jax.jit, static_argnames=("group_size",))
+def _weighted_sq_err(w, sal, xb, group_size: int) -> jax.Array:
+    """``Σ ((W·s − Q(W·s)) · x̂)²`` for one weight, fused by XLA so no
+    weight-sized f32 temporaries pile up (a full-width stacked weight is
+    gigabytes in f32)."""
+    ws = w.astype(jnp.float32) * sal
+    err = ws - fake_quantize(ws, group_size)
+    return jnp.sum((err * xb) ** 2)
+
+
 def _group_quant_loss(
     params, cfg: ModelConfig, col: StatsCollector, group: SM.Group,
     alpha: float, group_size: int,
@@ -43,13 +54,10 @@ def _group_quant_loss(
     x_hat = jnp.asarray(act / s)        # smoothed activation stats
     total = 0.0
     for wp in group.weights:
-        w = SM.tget(params, wp).astype(jnp.float32)
-        sal = SM._align(s, w)
-        ws = w * sal                    # smoothed weight
-        err = (ws - fake_quantize(ws, group_size)).astype(jnp.float32)
+        w = SM.tget(params, wp)
         extra = w.ndim - 1 - x_hat.ndim
         xb = x_hat.reshape(*x_hat.shape[:-1], *([1] * extra), x_hat.shape[-1], 1)
-        total += float(jnp.sum((err * xb) ** 2))
+        total += float(_weighted_sq_err(w, SM._align(s, w), xb, group_size))
     return total
 
 

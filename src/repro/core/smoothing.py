@@ -296,9 +296,21 @@ def assemble_stats(col: StatsCollector, block: Tuple[str, ...],
     ])
 
 
+# weight-sized elementwise passes run fused under jit: op by op, a stacked
+# full-width weight would materialize several gigabyte-sized f32 copies
+@jax.jit
+def _row_absmax(w: jax.Array) -> jax.Array:
+    return jnp.max(jnp.abs(w.astype(jnp.float32)), axis=-1)
+
+
+@jax.jit
+def _scale_rows(w: jax.Array, sal: jax.Array) -> jax.Array:
+    return (w.astype(jnp.float32) * sal).astype(w.dtype)
+
+
 def _w_absmax_in(w: jax.Array, stat_shape: Tuple[int, ...]) -> np.ndarray:
     """max_j |W[..., i, j]| reduced to ``stat_shape`` (= [*stat_lead, Ci])."""
-    a = np.asarray(jnp.max(jnp.abs(w.astype(jnp.float32)), axis=-1))
+    a = np.asarray(_row_absmax(w))
     while a.ndim > len(stat_shape):       # reduce extra middle dims (e.g. E)
         ax = a.ndim - 2                   # innermost lead dim
         a = a.max(axis=ax)
@@ -351,8 +363,7 @@ def apply_group(params, cfg: ModelConfig, group: Group, s: np.ndarray):
         return params
     for wp in group.weights + group.row_compensations:
         w = tget(params, wp)
-        sal = _align(s, w)
-        params = tset(params, wp, (w.astype(jnp.float32) * sal).astype(w.dtype))
+        params = tset(params, wp, _scale_rows(w, _align(s, w)))
     pk, pp = group.provider.kind, group.provider.path
     # a layer_reduce group has one shared s; its provider is a single
     # (unstacked) module, so drop the stacked layer dim before fusing

@@ -12,24 +12,7 @@
 ``ref.py`` holds the pure-jnp oracles the interpret-mode tests compare
 against.
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams across releases; take
-# whichever this version ships.  Kernel modules import this instead of
-# re-deriving it — it must be bound *before* the ops re-import below so the
-# submodules' ``from repro.kernels import tpu_compiler_params`` resolves
-# against the partially-initialised package.
-_CompilerParams = getattr(_pltpu, "CompilerParams", None) or getattr(
-    _pltpu, "TPUCompilerParams"
-)
-
-
-def tpu_compiler_params(**kwargs):
-    """Build the TPU ``compiler_params`` object for ``pl.pallas_call``."""
-    return _CompilerParams(**kwargs)
-
-
-from repro.kernels.ops import (  # noqa: E402,F401
+from repro.kernels.ops import (  # noqa: F401
     default_backend,
     gqa_paged_attention,
     mla_paged_attention,

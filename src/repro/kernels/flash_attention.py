@@ -25,8 +25,6 @@ NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_KV = 512
 
-from repro.kernels import tpu_compiler_params
-
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             n_kv: int, block_q: int, block_kv: int, causal: bool,
@@ -135,7 +133,7 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

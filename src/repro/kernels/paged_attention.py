@@ -14,23 +14,30 @@ pages past a sequence's valid length are clamped to the previous block index
 so the pipeline elides their copies — bytes moved scale with *live tokens*,
 not ``batch × max_pages``.
 
-Grids
-  GQA: ``(B, Hkv, P)``, pages innermost; each cell attends the slot's
-  ``grp = H/Hkv`` query heads for one KV head against one page.
-  MLA (absorbed form): ``(B, P)``; scores run in the latent space
-  (``q_lat·ckv + q_pe·kpe``) so the per-page work covers all H heads.
-  Chunked prefill: the same grids with one extra step — ``(B, Hkv, P+1)`` /
-  ``(B, P+1)`` — where queries arrive as a ``[B, T_chunk, …]`` block at true
-  positions ``prefix_len[b] + t``.  Steps ``0..P-1`` stream the cached
-  prefix pages (masked ``kv_pos < prefix_len``, no causal term needed since
-  every chunk query postdates the prefix); the final step attends the
-  chunk's own raw-fp K/V with a causal-within-chunk mask and flushes.
+Pool layout (``models/attention.py`` owns it): every page leaf is
+``[NP, H, PS, D]`` (``H = Hkv`` for GQA, ``1`` for MLA latents) and every
+int8 scale leaf ``[NP, H, PS]``.  One grid step DMAs one whole page — all
+its heads, one contiguous block — whose trailing block dims are ``(PS, D)``
+and ``(H, PS)``: full extents or tile multiples, as the TPU lowering needs.
 
-Online-softmax state (m, l, acc) lives in VMEM scratch, initialized at page
-0 and flushed on the last page step (same shape as ``flash_attention``).
+Grids
+  GQA decode: ``(B, P)``, pages innermost; each step attends every KV
+  head's ``grp = H/Hkv`` query heads against one page.
+  MLA decode (absorbed form): ``(B, P)``; scores run in the latent space
+  (``q_lat·ckv + q_pe·kpe``) so the per-page work covers all H heads.
+  Chunked prefill: ``(B, R/bq, P+1)`` — the chunk's ``R = T·grp`` (GQA) or
+  ``T·H`` (MLA) query rows tiled by ``bq`` (row ``r`` is token ``r // grp``
+  at true position ``prefix_len[b] + r // grp``).  Steps ``0..P-1`` stream
+  the cached prefix pages (masked ``kv_pos < prefix_len``, no causal term
+  needed since every chunk query postdates the prefix); the final step
+  attends the chunk's own raw-fp K/V with a causal-within-chunk mask and
+  flushes.
+
+Online-softmax state (m, l, acc) lives in VMEM scratch, one slice per KV
+head, initialized at page 0 and flushed on the last page step.
 
 Int8 pools: when scale operands are passed, K/V pages are int8 with per-row
-(position, head) f32 scales.  Scores are computed on the raw int8 codes
+(head, position) f32 scales.  Scores are computed on the raw int8 codes
 (cast to f32 for the MXU) and the scale is applied to the score/probability
 row — identical math to the jnp reference, half the page bytes.
 
@@ -52,8 +59,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-from repro.kernels import tpu_compiler_params
+# query rows per chunked-prefill grid step: bounds the VMEM held by the q
+# block, the f32 accumulator and the score tile for long chunks
+MAX_Q_ROWS = 512
 
 
 def _live_pages(length, page_size):
@@ -61,7 +69,88 @@ def _live_pages(length, page_size):
     return (length + page_size - 1) // page_size
 
 
-# ============================================================== GQA kernel ==
+def _row_block(rows: int) -> int:
+    """Query rows per prefill step: all of them up to :data:`MAX_Q_ROWS`,
+    else the largest multiple of 8 that divides ``rows`` within it."""
+    if rows <= MAX_Q_ROWS:
+        return rows
+    for bq in range(MAX_Q_ROWS, 7, -8):
+        if rows % bq == 0:
+            return bq
+    return rows
+
+
+def _scores(q, k):
+    """``q[R, D] · k[S, D]ᵀ -> [R, S]`` in f32."""
+    return jax.lax.dot_general(
+        q.astype(jnp.float32), k.astype(jnp.float32),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _init_state(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _softmax_step(m_ref, l_ref, acc_ref, h, s, valid, v, p_scale=None):
+    """Fold one score tile ``s[R, S]`` (masked by ``valid``) and its values
+    ``v[S, Dv]`` into head ``h``'s online-softmax state.  ``p_scale[1, S]``
+    carries int8 value pools' per-row scales onto the probabilities."""
+    s = jnp.where(valid, s, NEG_INF)
+    m_prev = m_ref[h]
+    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+    pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[h] = l_ref[h] * corr + pexp.sum(-1, keepdims=True)
+    if p_scale is not None:
+        pexp = pexp * p_scale
+    acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+        pexp, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[h] = m_new
+
+
+def _flush(o_ref, l_ref, acc_ref):
+    o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def _state_scratch(heads: int, rows: int, dv: int):
+    return [pltpu.VMEM((heads, rows, 1), jnp.float32),
+            pltpu.VMEM((heads, rows, 1), jnp.float32),
+            pltpu.VMEM((heads, rows, dv), jnp.float32)]
+
+
+def _page_maps(pages: int, ps: int, prefill: bool):
+    """Index maps (data leaf, scale leaf) of the pool page a grid step reads.
+
+    Decode grids ``(B, P)`` clamp to the slot's valid length; prefill grids
+    ``(B, R/bq, P+1)`` clamp to its cached prefix, kept >= 1 page so cold
+    rows (prefix 0) and the suffix step P stay in range.  Dead steps
+    re-map to the last live page: the pipeline sees the same block index
+    as the previous step and elides the DMA entirely."""
+    def page(bi, pi, tbl, n_valid):
+        live = _live_pages(n_valid, ps)
+        if prefill:
+            live = jnp.maximum(live, 1)
+        return tbl[bi * pages + jnp.minimum(pi, live - 1)]
+
+    if prefill:
+        def data_map(bi, qi, pi, tbl, pfx, cln):
+            return (page(bi, pi, tbl, pfx[bi]), 0, 0, 0)
+
+        def scale_map(bi, qi, pi, tbl, pfx, cln):
+            return (page(bi, pi, tbl, pfx[bi]), 0, 0)
+    else:
+        def data_map(bi, pi, tbl, lens):
+            return (page(bi, pi, tbl, lens[bi]), 0, 0, 0)
+
+        def scale_map(bi, pi, tbl, lens):
+            return (page(bi, pi, tbl, lens[bi]), 0, 0)
+    return data_map, scale_map
+
+
+# ============================================================== GQA decode ==
 def _gqa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
                 page_size: int, n_pages: int, scale: float, quant: bool):
     if quant:
@@ -69,107 +158,72 @@ def _gqa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     length = len_ref[b]
     page_start = p * page_size
 
     @pl.when(page_start < length)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)            # [grp, Dh]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [PS, Dh]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)      # [PS, Dv]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                      # [grp, PS]
-        if quant:
-            # int8 codes hit the MXU; the per-row scale lands on the (tiny)
-            # score row — mirrors the jnp int8 reference exactly
-            s = s * ks_ref[0, :, 0][None, :]
-        pos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = pos < length
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + pexp.sum(-1, keepdims=True)
-        if quant:
-            pexp = pexp * vs_ref[0, :, 0][None, :]
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            pexp, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
+        for h in range(q_ref.shape[1]):
+            s = _scores(q_ref[0, h], k_ref[0, h]) * scale     # [grp, PS]
+            if quant:
+                # int8 codes hit the MXU; the per-row scale lands on the
+                # (tiny) score row — mirrors the jnp int8 reference exactly
+                s = s * ks_ref[0, h:h + 1, :]
+            pos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            _softmax_step(m_ref, l_ref, acc_ref, h, s, pos < length,
+                          v_ref[0, h],
+                          vs_ref[0, h:h + 1, :] if quant else None)
 
     @pl.when(p == n_pages - 1)
-    def _flush():
-        o_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    def _out():
+        _flush(o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
 def gqa_paged_attention(
     q: jax.Array,               # [B, Hkv, grp, Dh] one decode token
-    k_pool: jax.Array,          # [NP, PS, Hkv, Dh] (bf16/f32 or int8)
-    v_pool: jax.Array,          # [NP, PS, Hkv, Dv]
+    k_pool: jax.Array,          # [NP, Hkv, PS, Dh] (bf16/f32 or int8)
+    v_pool: jax.Array,          # [NP, Hkv, PS, Dv]
     table_rows: jax.Array,      # [B, P] int32 pool page per logical page
     lengths: jax.Array,         # [B] int32 valid rows incl. this step's token
-    k_scale: jax.Array | None = None,   # [NP, PS, Hkv] f32 (int8 pools)
+    k_scale: jax.Array | None = None,   # [NP, Hkv, PS] f32 (int8 pools)
     v_scale: jax.Array | None = None,
     *,
     sm_scale: float,
     interpret: bool = False,
 ) -> jax.Array:                 # [B, Hkv, grp, Dv] f32
     b, hkv, grp, dh = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     dv = v_pool.shape[-1]
     pages = table_rows.shape[1]
     quant = k_scale is not None
-    flat_tbl = table_rows.reshape(-1).astype(jnp.int32)
-    lengths = lengths.astype(jnp.int32)
+    data_map, scale_map = _page_maps(pages, ps, prefill=False)
 
-    def pool_map(bi, hi, pi, tbl, lens):
-        # dead pages re-map to the last live page: the pipeline sees the same
-        # block index as the previous step and elides the DMA entirely
-        pp = jnp.minimum(pi, _live_pages(lens[bi], ps) - 1)
-        return (tbl[bi * pages + pp], 0, hi, 0)
-
-    def scale_map(bi, hi, pi, tbl, lens):
-        pp = jnp.minimum(pi, _live_pages(lens[bi], ps) - 1)
-        return (tbl[bi * pages + pp], 0, hi)
+    def fixed(bi, pi, tbl, lens):
+        return (bi, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, grp, dh), lambda bi, hi, pi, tbl, lens: (bi, hi, 0, 0)),
-        pl.BlockSpec((1, ps, 1, dh), pool_map),
-        pl.BlockSpec((1, ps, 1, dv), pool_map),
+        pl.BlockSpec((1, hkv, grp, dh), fixed),
+        pl.BlockSpec((1, hkv, ps, dh), data_map),
+        pl.BlockSpec((1, hkv, ps, dv), data_map),
     ]
     operands = [q, k_pool, v_pool]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, ps, 1), scale_map),
-            pl.BlockSpec((1, ps, 1), scale_map),
-        ]
+        in_specs += [pl.BlockSpec((1, hkv, ps), scale_map)] * 2
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, pages),
+        grid=(b, pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, grp, dv), lambda bi, hi, pi, tbl, lens: (bi, hi, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((grp, 1), jnp.float32),
-            pltpu.VMEM((grp, 1), jnp.float32),
-            pltpu.VMEM((grp, dv), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, hkv, grp, dv), fixed),
+        scratch_shapes=_state_scratch(hkv, grp, dv),
     )
     return pl.pallas_call(
         functools.partial(
@@ -178,14 +232,15 @@ def gqa_paged_attention(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, grp, dv), jnp.float32),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(flat_tbl, lengths, *operands)
+    )(table_rows.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
+      *operands)
 
 
-# ============================================================== MLA kernel ==
+# ============================================================== MLA decode ==
 def _mla_kernel(tbl_ref, len_ref, qlat_ref, qpe_ref, ckv_ref, kpe_ref, *rest,
                 page_size: int, n_pages: int, scale: float, quant: bool):
     if quant:
@@ -197,62 +252,39 @@ def _mla_kernel(tbl_ref, len_ref, qlat_ref, qpe_ref, ckv_ref, kpe_ref, *rest,
 
     @pl.when(p == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     length = len_ref[b]
     page_start = p * page_size
 
     @pl.when(page_start < length)
     def _compute():
-        q_lat = qlat_ref[0].astype(jnp.float32)        # [H, r]
-        q_pe = qpe_ref[0].astype(jnp.float32)          # [H, dr]
-        ckv = ckv_ref[0].astype(jnp.float32)           # [PS, r]
-        kpe = kpe_ref[0].astype(jnp.float32)           # [PS, dr]
-        s_lat = jax.lax.dot_general(
-            q_lat, ckv, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                              # [H, PS]
-        s_pe = jax.lax.dot_general(
-            q_pe, kpe, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        ckv = ckv_ref[0, 0]                            # [PS, r]
+        s_lat = _scores(qlat_ref[0], ckv)              # [H, PS]
+        s_pe = _scores(qpe_ref[0], kpe_ref[0, 0])
         if quant:
-            s_lat = s_lat * cs_ref[0][None, :]
-            s_pe = s_pe * ps_ref[0][None, :]
+            s_lat = s_lat * cs_ref[0]
+            s_pe = s_pe * ps_ref[0]
         s = (s_lat + s_pe) * scale
         pos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = pos < length
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + pexp.sum(-1, keepdims=True)
-        if quant:
-            # o_lat = Σ p·(s_j·ckv_j) = (p ⊙ s) @ ckv_int8
-            pexp = pexp * cs_ref[0][None, :]
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            pexp, ckv, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
+        # o_lat = Σ p·(s_j·ckv_j) = (p ⊙ s) @ ckv_int8
+        _softmax_step(m_ref, l_ref, acc_ref, 0, s, pos < length, ckv,
+                      cs_ref[0] if quant else None)
 
     @pl.when(p == n_pages - 1)
-    def _flush():
-        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    def _out():
+        o_ref[0] = acc_ref[0] / jnp.maximum(l_ref[0], 1e-30)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
 def mla_paged_attention(
     q_lat: jax.Array,           # [B, H, r] absorbed query (q_nope · w_k)
     q_pe: jax.Array,            # [B, H, dr] rope query
-    ckv_pool: jax.Array,        # [NP, PS, r] latent pool (bf16/f32 or int8)
-    kpe_pool: jax.Array,        # [NP, PS, dr]
+    ckv_pool: jax.Array,        # [NP, 1, PS, r] latent pool (bf16/f32 or int8)
+    kpe_pool: jax.Array,        # [NP, 1, PS, dr]
     table_rows: jax.Array,      # [B, P] int32
     lengths: jax.Array,         # [B] int32 valid rows incl. this token
-    ckv_scale: jax.Array | None = None,  # [NP, PS] f32 (int8 pools)
+    ckv_scale: jax.Array | None = None,  # [NP, 1, PS] f32 (int8 pools)
     kpe_scale: jax.Array | None = None,
     *,
     sm_scale: float,
@@ -260,30 +292,20 @@ def mla_paged_attention(
 ) -> jax.Array:                 # [B, H, r] f32 latent output
     b, h, r = q_lat.shape
     dr = q_pe.shape[-1]
-    ps = ckv_pool.shape[1]
+    ps = ckv_pool.shape[2]
     pages = table_rows.shape[1]
     quant = ckv_scale is not None
-    flat_tbl = table_rows.reshape(-1).astype(jnp.int32)
-    lengths = lengths.astype(jnp.int32)
-
-    def pool_map(bi, pi, tbl, lens):
-        pp = jnp.minimum(pi, _live_pages(lens[bi], ps) - 1)
-        return (tbl[bi * pages + pp], 0, 0)
-
-    def scale_map(bi, pi, tbl, lens):
-        pp = jnp.minimum(pi, _live_pages(lens[bi], ps) - 1)
-        return (tbl[bi * pages + pp], 0)
+    data_map, scale_map = _page_maps(pages, ps, prefill=False)
 
     in_specs = [
         pl.BlockSpec((1, h, r), lambda bi, pi, tbl, lens: (bi, 0, 0)),
         pl.BlockSpec((1, h, dr), lambda bi, pi, tbl, lens: (bi, 0, 0)),
-        pl.BlockSpec((1, ps, r), pool_map),
-        pl.BlockSpec((1, ps, dr), pool_map),
+        pl.BlockSpec((1, 1, ps, r), data_map),
+        pl.BlockSpec((1, 1, ps, dr), data_map),
     ]
     operands = [q_lat, q_pe, ckv_pool, kpe_pool]
     if quant:
-        in_specs += [pl.BlockSpec((1, ps), scale_map),
-                     pl.BlockSpec((1, ps), scale_map)]
+        in_specs += [pl.BlockSpec((1, 1, ps), scale_map)] * 2
         operands += [ckv_scale, kpe_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -291,11 +313,7 @@ def mla_paged_attention(
         grid=(b, pages),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, h, r), lambda bi, pi, tbl, lens: (bi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, r), jnp.float32),
-        ],
+        scratch_shapes=_state_scratch(1, h, r),
     )
     return pl.pallas_call(
         functools.partial(
@@ -304,89 +322,62 @@ def mla_paged_attention(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, r), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(flat_tbl, lengths, *operands)
+    )(table_rows.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
+      *operands)
 
 
 # ================================================== chunked prefill (GQA) ==
 def _gqa_prefill_kernel(tbl_ref, pfx_ref, cln_ref, q_ref, ksuf_ref, vsuf_ref,
                         k_ref, v_ref, *rest, page_size: int, n_pages: int,
-                        t_chunk: int, grp: int, scale: float, quant: bool):
+                        grp: int, block_rows: int, scale: float, quant: bool):
     if quant:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
+    qi = pl.program_id(1)
     p = pl.program_id(2)
+    hkv = q_ref.shape[1]
 
     @pl.when(p == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     prefix = pfx_ref[b]
     chunk = cln_ref[b]
-    rows = t_chunk * grp
-
-    def _q_rows():
-        return q_ref[0, :, 0].astype(jnp.float32).reshape(rows, -1)
-
-    def _update(s, valid, v, v_row_scale):
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + pexp.sum(-1, keepdims=True)
-        if v_row_scale is not None:
-            pexp = pexp * v_row_scale
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            pexp, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
 
     # grid steps 0..P-1: the cached prefix, one pool page per step — every
     # chunk query sits at position >= prefix, so the only mask is raggedness
     @pl.when((p < n_pages) & (p * page_size < prefix))
     def _pages():
-        q = _q_rows()                                   # [T*grp, Dh]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # [PS, Dh]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)       # [PS, Dv]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                       # [T*grp, PS]
-        if quant:
-            s = s * ks_ref[0, :, 0][None, :]
-        pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _update(s, pos < prefix, v,
-                vs_ref[0, :, 0][None, :] if quant else None)
+        for h in range(hkv):
+            s = _scores(q_ref[0, h], k_ref[0, h]) * scale     # [bq, PS]
+            if quant:
+                s = s * ks_ref[0, h:h + 1, :]
+            pos = p * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            _softmax_step(m_ref, l_ref, acc_ref, h, s, pos < prefix,
+                          v_ref[0, h],
+                          vs_ref[0, h:h + 1, :] if quant else None)
 
     # final grid step: the chunk attends its own raw-fp K/V (the rows being
     # written this step) with a causal-within-chunk mask — no int8 scale, so
-    # cold/warm chunks keep the slab-prefill numerics bit-for-bit
+    # cold/warm chunks keep the slab-prefill numerics bit-for-bit.  Row r is
+    # token r // grp, so key j is visible iff j·grp <= r.
     @pl.when(p == n_pages)
     def _suffix():
-        q = _q_rows()
-        k = ksuf_ref[0, :, 0, :].astype(jnp.float32)    # [T, Dh]
-        v = vsuf_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                       # [T*grp, T]
-        tq = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // grp
-        j = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _update(s, (j <= tq) & (j < chunk), v, None)
-
-    @pl.when(p == n_pages)
-    def _flush():
-        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0] = o.reshape(t_chunk, grp, -1)
+        for h in range(hkv):
+            s = _scores(q_ref[0, h], ksuf_ref[0, h]) * scale  # [bq, T]
+            row = qi * block_rows + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            j = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            _softmax_step(m_ref, l_ref, acc_ref, h, s,
+                          (j * grp <= row) & (j < chunk), vsuf_ref[0, h])
+        _flush(o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
@@ -394,12 +385,12 @@ def gqa_paged_prefill(
     q: jax.Array,               # [B, T, Hkv, grp, Dh] chunk queries
     k_suf: jax.Array,           # [B, T, Hkv, Dh] raw chunk keys (pre-quant)
     v_suf: jax.Array,           # [B, T, Hkv, Dv]
-    k_pool: jax.Array,          # [NP, PS, Hkv, Dh] (bf16/f32 or int8)
-    v_pool: jax.Array,          # [NP, PS, Hkv, Dv]
+    k_pool: jax.Array,          # [NP, Hkv, PS, Dh] (bf16/f32 or int8)
+    v_pool: jax.Array,          # [NP, Hkv, PS, Dv]
     table_rows: jax.Array,      # [B, P] int32 pool page per logical page
     prefix_len: jax.Array,      # [B] int32 tokens already in the pages
     chunk_len: jax.Array,       # [B] int32 valid rows of this chunk (<= T)
-    k_scale: jax.Array | None = None,   # [NP, PS, Hkv] f32 (int8 pools)
+    k_scale: jax.Array | None = None,   # [NP, Hkv, PS] f32 (int8 pools)
     v_scale: jax.Array | None = None,
     *,
     sm_scale: float,
@@ -407,162 +398,111 @@ def gqa_paged_prefill(
 ) -> jax.Array:                 # [B, T, Hkv, grp, Dv] f32
     """Chunked-prefill attention straight off the paged pools.
 
-    Grid ``(B, Hkv, P+1)``: steps ``0..P-1`` DMA prefix pages by block table
-    (dead pages clamp to the last live one, eliding the copy — same contract
-    as the decode grid); the final step attends the chunk's own raw-fp
-    suffix K/V with a causal mask ``j <= t`` and flushes.  Every query row
-    ``t`` sits at true position ``prefix_len[b] + t``, which is >= any
-    prefix position, so prefix steps need no causal term.
+    Grid ``(B, T·grp/bq, P+1)``: steps ``0..P-1`` DMA prefix pages by block
+    table (dead pages clamp to the last live one, eliding the copy — same
+    contract as the decode grid); the final step attends the chunk's own
+    raw-fp suffix K/V with a causal mask ``j <= t`` and flushes.  Every
+    query row ``t`` sits at true position ``prefix_len[b] + t``, which is
+    >= any prefix position, so prefix steps need no causal term.  Queries
+    and suffix K/V are moved head-major (``[B, Hkv, rows, D]``) so each KV
+    head's rows are one contiguous ``(rows, D)`` tile.
     """
     b, t, hkv, grp, dh = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     dv = v_pool.shape[-1]
     pages = table_rows.shape[1]
     quant = k_scale is not None
-    flat_tbl = table_rows.reshape(-1).astype(jnp.int32)
-    prefix_len = prefix_len.astype(jnp.int32)
-    chunk_len = chunk_len.astype(jnp.int32)
+    rows = t * grp
+    bq = _row_block(rows)
+    qh = q.transpose(0, 2, 1, 3, 4).reshape(b, hkv, rows, dh)
+    kh = k_suf.transpose(0, 2, 1, 3)
+    vh = v_suf.transpose(0, 2, 1, 3)
+    data_map, scale_map = _page_maps(pages, ps, prefill=True)
 
-    def pool_map(bi, hi, pi, tbl, pfx, cln):
-        # clamp past-prefix steps (incl. the suffix step P) to the last live
-        # prefix page; max(live, 1) keeps cold rows (prefix 0) in range
-        live = jnp.maximum(_live_pages(pfx[bi], ps), 1)
-        pp = jnp.minimum(pi, live - 1)
-        return (tbl[bi * pages + pp], 0, hi, 0)
-
-    def scale_map(bi, hi, pi, tbl, pfx, cln):
-        live = jnp.maximum(_live_pages(pfx[bi], ps), 1)
-        pp = jnp.minimum(pi, live - 1)
-        return (tbl[bi * pages + pp], 0, hi)
-
-    def fixed(bi, hi, pi, tbl, pfx, cln):
-        return (bi, 0, hi, 0)
+    def fixed(bi, qi, pi, tbl, pfx, cln):
+        return (bi, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, t, 1, grp, dh),
-                     lambda bi, hi, pi, tbl, pfx, cln: (bi, 0, hi, 0, 0)),
-        pl.BlockSpec((1, t, 1, dh), fixed),
-        pl.BlockSpec((1, t, 1, dv), fixed),
-        pl.BlockSpec((1, ps, 1, dh), pool_map),
-        pl.BlockSpec((1, ps, 1, dv), pool_map),
+        pl.BlockSpec((1, hkv, bq, dh),
+                     lambda bi, qi, pi, tbl, pfx, cln: (bi, 0, qi, 0)),
+        pl.BlockSpec((1, hkv, t, dh), fixed),
+        pl.BlockSpec((1, hkv, t, dv), fixed),
+        pl.BlockSpec((1, hkv, ps, dh), data_map),
+        pl.BlockSpec((1, hkv, ps, dv), data_map),
     ]
-    operands = [q, k_suf, v_suf, k_pool, v_pool]
+    operands = [qh, kh, vh, k_pool, v_pool]
     if quant:
-        in_specs += [pl.BlockSpec((1, ps, 1), scale_map),
-                     pl.BlockSpec((1, ps, 1), scale_map)]
+        in_specs += [pl.BlockSpec((1, hkv, ps), scale_map)] * 2
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hkv, pages + 1),
+        grid=(b, rows // bq, pages + 1),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, t, 1, grp, dv),
-            lambda bi, hi, pi, tbl, pfx, cln: (bi, 0, hi, 0, 0),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((t * grp, 1), jnp.float32),
-            pltpu.VMEM((t * grp, 1), jnp.float32),
-            pltpu.VMEM((t * grp, dv), jnp.float32),
-        ],
+            (1, hkv, bq, dv),
+            lambda bi, qi, pi, tbl, pfx, cln: (bi, 0, qi, 0)),
+        scratch_shapes=_state_scratch(hkv, bq, dv),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
-            _gqa_prefill_kernel, page_size=ps, n_pages=pages, t_chunk=t,
-            grp=grp, scale=sm_scale, quant=quant,
+            _gqa_prefill_kernel, page_size=ps, n_pages=pages, grp=grp,
+            block_rows=bq, scale=sm_scale, quant=quant,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, t, hkv, grp, dv), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, dv), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(flat_tbl, prefix_len, chunk_len, *operands)
+    )(table_rows.reshape(-1).astype(jnp.int32), prefix_len.astype(jnp.int32),
+      chunk_len.astype(jnp.int32), *operands)
+    return out.reshape(b, hkv, t, grp, dv).transpose(0, 2, 1, 3, 4)
 
 
 # ================================================== chunked prefill (MLA) ==
 def _mla_prefill_kernel(tbl_ref, pfx_ref, cln_ref, qlat_ref, qpe_ref,
                         csuf_ref, psuf_ref, ckv_ref, kpe_ref, *rest,
-                        page_size: int, n_pages: int, t_chunk: int,
-                        heads: int, scale: float, quant: bool):
+                        page_size: int, n_pages: int, heads: int,
+                        block_rows: int, scale: float, quant: bool):
     if quant:
         cs_ref, pscl_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
-    p = pl.program_id(1)
+    qi = pl.program_id(1)
+    p = pl.program_id(2)
 
     @pl.when(p == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     prefix = pfx_ref[b]
     chunk = cln_ref[b]
-    rows = t_chunk * heads
-
-    def _q_rows():
-        q_lat = qlat_ref[0].astype(jnp.float32).reshape(rows, -1)
-        q_pe = qpe_ref[0].astype(jnp.float32).reshape(rows, -1)
-        return q_lat, q_pe
-
-    def _update(s, valid, v, v_row_scale):
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + pexp.sum(-1, keepdims=True)
-        if v_row_scale is not None:
-            pexp = pexp * v_row_scale
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            pexp, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
 
     @pl.when((p < n_pages) & (p * page_size < prefix))
     def _pages():
-        q_lat, q_pe = _q_rows()                         # [T*H, r], [T*H, dr]
-        ckv = ckv_ref[0].astype(jnp.float32)            # [PS, r]
-        kpe = kpe_ref[0].astype(jnp.float32)            # [PS, dr]
-        s_lat = jax.lax.dot_general(
-            q_lat, ckv, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        s_pe = jax.lax.dot_general(
-            q_pe, kpe, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        ckv = ckv_ref[0, 0]                             # [PS, r]
+        s_lat = _scores(qlat_ref[0], ckv)               # [bq, PS]
+        s_pe = _scores(qpe_ref[0], kpe_ref[0, 0])
         if quant:
-            s_lat = s_lat * cs_ref[0][None, :]
-            s_pe = s_pe * pscl_ref[0][None, :]
-        s = (s_lat + s_pe) * scale                      # [T*H, PS]
+            s_lat = s_lat * cs_ref[0]
+            s_pe = s_pe * pscl_ref[0]
+        s = (s_lat + s_pe) * scale
         pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _update(s, pos < prefix, ckv,
-                cs_ref[0][None, :] if quant else None)
+        _softmax_step(m_ref, l_ref, acc_ref, 0, s, pos < prefix, ckv,
+                      cs_ref[0] if quant else None)
 
     @pl.when(p == n_pages)
     def _suffix():
-        q_lat, q_pe = _q_rows()
-        ckv_s = csuf_ref[0].astype(jnp.float32)         # [T, r] raw latent
-        kpe_s = psuf_ref[0].astype(jnp.float32)         # [T, dr]
-        s = (jax.lax.dot_general(
-            q_lat, ckv_s, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) + jax.lax.dot_general(
-            q_pe, kpe_s, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )) * scale                                      # [T*H, T]
-        tq = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // heads
+        ckv_s = csuf_ref[0]                             # [T, r] raw latent
+        s = (_scores(qlat_ref[0], ckv_s)
+             + _scores(qpe_ref[0], psuf_ref[0])) * scale  # [bq, T]
+        row = qi * block_rows + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         j = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _update(s, (j <= tq) & (j < chunk), ckv_s, None)
-
-    @pl.when(p == n_pages)
-    def _flush():
-        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = o.reshape(t_chunk, heads, -1)
+        _softmax_step(m_ref, l_ref, acc_ref, 0, s,
+                      (j * heads <= row) & (j < chunk), ckv_s)
+        o_ref[0] = acc_ref[0] / jnp.maximum(l_ref[0], 1e-30)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
@@ -571,77 +511,69 @@ def mla_paged_prefill(
     q_pe: jax.Array,            # [B, T, H, dr]
     ckv_suf: jax.Array,         # [B, T, r] raw chunk latent (pre-quant)
     kpe_suf: jax.Array,         # [B, T, dr]
-    ckv_pool: jax.Array,        # [NP, PS, r] (bf16/f32 or int8)
-    kpe_pool: jax.Array,        # [NP, PS, dr]
+    ckv_pool: jax.Array,        # [NP, 1, PS, r] (bf16/f32 or int8)
+    kpe_pool: jax.Array,        # [NP, 1, PS, dr]
     table_rows: jax.Array,      # [B, P] int32
     prefix_len: jax.Array,      # [B] int32 tokens already in the pages
     chunk_len: jax.Array,       # [B] int32 valid rows of this chunk (<= T)
-    ckv_scale: jax.Array | None = None,  # [NP, PS] f32 (int8 pools)
+    ckv_scale: jax.Array | None = None,  # [NP, 1, PS] f32 (int8 pools)
     kpe_scale: jax.Array | None = None,
     *,
     sm_scale: float,
     interpret: bool = False,
 ) -> jax.Array:                 # [B, T, H, r] f32 latent output
-    """MLA chunked prefill in absorbed form, same grid story as GQA but
-    ``(B, P+1)`` — latent scores cover all H heads per page step."""
+    """MLA chunked prefill in absorbed form, same grid story as GQA —
+    ``(B, T·H/bq, P+1)``, latent scores cover all H heads per page step."""
     b, t, h, r = q_lat.shape
     dr = q_pe.shape[-1]
-    ps = ckv_pool.shape[1]
+    ps = ckv_pool.shape[2]
     pages = table_rows.shape[1]
     quant = ckv_scale is not None
-    flat_tbl = table_rows.reshape(-1).astype(jnp.int32)
-    prefix_len = prefix_len.astype(jnp.int32)
-    chunk_len = chunk_len.astype(jnp.int32)
+    rows = t * h
+    bq = _row_block(rows)
+    data_map, scale_map = _page_maps(pages, ps, prefill=True)
 
-    def pool_map(bi, pi, tbl, pfx, cln):
-        live = jnp.maximum(_live_pages(pfx[bi], ps), 1)
-        pp = jnp.minimum(pi, live - 1)
-        return (tbl[bi * pages + pp], 0, 0)
+    def q_map(bi, qi, pi, tbl, pfx, cln):
+        return (bi, qi, 0)
 
-    def scale_map(bi, pi, tbl, pfx, cln):
-        live = jnp.maximum(_live_pages(pfx[bi], ps), 1)
-        pp = jnp.minimum(pi, live - 1)
-        return (tbl[bi * pages + pp], 0)
+    def fixed(bi, qi, pi, tbl, pfx, cln):
+        return (bi, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, t, h, r), lambda bi, pi, tbl, pfx, cln: (bi, 0, 0, 0)),
-        pl.BlockSpec((1, t, h, dr), lambda bi, pi, tbl, pfx, cln: (bi, 0, 0, 0)),
-        pl.BlockSpec((1, t, r), lambda bi, pi, tbl, pfx, cln: (bi, 0, 0)),
-        pl.BlockSpec((1, t, dr), lambda bi, pi, tbl, pfx, cln: (bi, 0, 0)),
-        pl.BlockSpec((1, ps, r), pool_map),
-        pl.BlockSpec((1, ps, dr), pool_map),
+        pl.BlockSpec((1, bq, r), q_map),
+        pl.BlockSpec((1, bq, dr), q_map),
+        pl.BlockSpec((1, t, r), fixed),
+        pl.BlockSpec((1, t, dr), fixed),
+        pl.BlockSpec((1, 1, ps, r), data_map),
+        pl.BlockSpec((1, 1, ps, dr), data_map),
     ]
-    operands = [q_lat, q_pe, ckv_suf, kpe_suf, ckv_pool, kpe_pool]
+    operands = [q_lat.reshape(b, rows, r), q_pe.reshape(b, rows, dr),
+                ckv_suf, kpe_suf, ckv_pool, kpe_pool]
     if quant:
-        in_specs += [pl.BlockSpec((1, ps), scale_map),
-                     pl.BlockSpec((1, ps), scale_map)]
+        in_specs += [pl.BlockSpec((1, 1, ps), scale_map)] * 2
         operands += [ckv_scale, kpe_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, pages + 1),
+        grid=(b, rows // bq, pages + 1),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, t, h, r), lambda bi, pi, tbl, pfx, cln: (bi, 0, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((t * h, 1), jnp.float32),
-            pltpu.VMEM((t * h, 1), jnp.float32),
-            pltpu.VMEM((t * h, r), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, bq, r), q_map),
+        scratch_shapes=_state_scratch(1, bq, r),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
-            _mla_prefill_kernel, page_size=ps, n_pages=pages, t_chunk=t,
-            heads=h, scale=sm_scale, quant=quant,
+            _mla_prefill_kernel, page_size=ps, n_pages=pages, heads=h,
+            block_rows=bq, scale=sm_scale, quant=quant,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, t, h, r), jnp.float32),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary"),
+        out_shape=jax.ShapeDtypeStruct((b, rows, r), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(flat_tbl, prefix_len, chunk_len, *operands)
+    )(table_rows.reshape(-1).astype(jnp.int32), prefix_len.astype(jnp.int32),
+      chunk_len.astype(jnp.int32), *operands)
+    return out.reshape(b, t, h, r)
 
 
 # ====================================================== roofline estimates ==

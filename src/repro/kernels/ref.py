@@ -27,8 +27,8 @@ def w4a16_matmul_ref(x: jax.Array, qt: QuantizedTensor) -> jax.Array:
 
 def _folded_int_codes(qt: QuantizedTensor) -> jax.Array:
     """Zero-point-folded integer weight codes ``[..., G#, Gs, Co]`` (f32 but
-    integer-valued), mirroring the kernels' ``_dequant_block_i8`` exactly —
-    including its int8 clip for pathological offset-only groups."""
+    integer-valued), mirroring the kernels' A8 fold (``step_contribution``)
+    exactly — including its int8 clip for pathological offset-only groups."""
     q = unpack_codes(qt.packed, qt.group_size).astype(jnp.float32)
     *lead, ci, co = q.shape
     g = qt.scales.shape[-2]

@@ -11,11 +11,11 @@ decode projections (``models/attention.py``, heads ride the grid); both used
 to re-inflate a dense f32 weight in HBM every step via ``dequantize``.
 
 Layout contract is identical to ``w4a16_matmul``: group-split packing along
-the contraction axis, the contraction block pinned to the quantization group
-so each grid step unpacks with one sublane ``concat`` and uses exactly one
-``scales``/``zeros`` row.  Zero-padded capacity rows (ragged MoE dispatch)
-are harmless: a zero activation row contributes a zero output row regardless
-of the asymmetric zero-points.
+the contraction axis, each k step spanning whole quantization groups with a
+``(gpk, bco)`` scales/zeros block, each group unpacked with one sublane
+``concat`` and contracted with its own scale row.  Zero-padded capacity rows
+(ragged MoE dispatch) are harmless: a zero activation row contributes a zero
+output row regardless of the asymmetric zero-points.
 """
 from __future__ import annotations
 
@@ -27,63 +27,34 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantize import QuantizedTensor, quantize_acts_per_token
-from repro.kernels import tpu_compiler_params
 from repro.kernels.w4a16_matmul import (
-    _dequant_block,
-    _dequant_block_i8,
     _fit_block_co,
     _round_up,
+    groups_per_step,
+    step_contribution,
+    weight_specs,
 )
 
 DEFAULT_BLOCK_C = 256
 DEFAULT_BLOCK_CO = 256
 
 
-def _kernel(x_ref, packed_ref, scales_ref, zeros_ref, o_ref, acc_ref, *, n_k):
+def _kernel(*refs, n_k, group, a8):
+    if a8:
+        x_ref, xs_ref, packed_ref, scales_ref, zeros_ref, o_ref, acc_ref = refs
+        xs = xs_ref[0]
+    else:
+        x_ref, packed_ref, scales_ref, zeros_ref, o_ref, acc_ref = refs
+        xs = None
     k = pl.program_id(3)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # this expert's block: packed (bci//2, bco) uint8; scales/zeros (1, bco)
-    w = _dequant_block(packed_ref[0], scales_ref[0], zeros_ref[0])
-    x = x_ref[0].astype(jnp.float32)  # (bc, bci)
-    acc_ref[...] += jax.lax.dot_general(
-        x,
-        w,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(k == n_k - 1)
-    def _flush():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _kernel_a8(
-    x_ref, xs_ref, packed_ref, scales_ref, zeros_ref, o_ref, acc_ref, *, n_k
-):
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # this expert's block: x (bc, bci) int8; xs (bc, 1) f32
-    wq = _dequant_block_i8(packed_ref[0], zeros_ref[0])
-    part = jax.lax.dot_general(
-        x_ref[0],
-        wq,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
-    # per-(row, group) rescale at each group boundary (see w4a16_matmul)
-    acc_ref[...] += (
-        part.astype(jnp.float32)
-        * scales_ref[0].astype(jnp.float32)
-        * xs_ref[0]
-    )
+    # this expert's block (see w4a16_matmul.step_contribution)
+    acc_ref[...] += step_contribution(
+        x_ref[0], xs, packed_ref[0], scales_ref[0], zeros_ref[0], group=group)
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -105,10 +76,10 @@ def w4a16_grouped_matmul(
     """``x[E, C, D] @ dequant(qt)[E, D, F] -> [E, C, F]`` via Pallas.
 
     One grid cell touches one expert only, so sharding the expert axis (EP)
-    shards the grid.  The contraction block is pinned to the quantization
-    group size (whole groups per step, one scales/zeros row).  ``act="a8"``
-    quantizes each ``(expert, row)`` to symmetric int8 outside the kernel and
-    runs the int8×int4→int32 body; zero-padded capacity rows quantize to
+    shards the grid.  The contraction block spans whole quantization groups
+    (one scales/zeros row per group).  ``act="a8"`` quantizes each
+    ``(expert, row)`` to symmetric int8 outside the kernel and runs the
+    int8×int4→int32 body; zero-padded capacity rows quantize to
     all-zero codes and still contribute zero output rows.
     """
     if qt.packed.ndim != 3:
@@ -128,48 +99,41 @@ def w4a16_grouped_matmul(
     group = qt.group_size
     out_dtype = x.dtype
 
-    if act == "a8":
+    a8 = act == "a8"
+    if a8:
         x, xs = quantize_acts_per_token(x)  # int8 codes, (e, c, 1) f32
 
     # decode-sized c (< block_c, e.g. MLA absorbed B rows per head): bc pins
-    # to the 8-padded row count — one C-grid step, cached per shape
-    bc = min(block_c, _round_up(c, 8))
+    # to the padded row count — one C-grid step, cached per shape.  int8
+    # rows tile by 32 sublanes, float rows by 8.
+    bc = min(block_c, _round_up(c, 32 if a8 else 8))
     c_pad = _round_up(c, bc)
     if c_pad != c:
         x = jnp.pad(x, ((0, 0), (0, c_pad - c), (0, 0)))
-        if act == "a8":
+        if a8:
             xs = jnp.pad(xs, ((0, 0), (0, c_pad - c), (0, 0)))
     bco = _fit_block_co(co, block_co)
-    n_c, n_co, n_k = c_pad // bc, co // bco, d // group
+    gpk = groups_per_step(d // group, group)
+    bci = gpk * group
+    n_c, n_co, n_k = c_pad // bc, co // bco, d // bci
 
-    if act == "a8":
-        kernel = functools.partial(_kernel_a8, n_k=n_k)
-        operands = (x, xs, qt.packed, qt.scales, qt.zeros)
-        in_specs = [
-            pl.BlockSpec((1, bc, group), lambda e, i, j, k: (e, i, k)),
-            pl.BlockSpec((1, bc, 1), lambda e, i, j, k: (e, i, 0)),
-            pl.BlockSpec((1, group // 2, bco), lambda e, i, j, k: (e, k, j)),
-            pl.BlockSpec((1, 1, bco), lambda e, i, j, k: (e, k, j)),
-            pl.BlockSpec((1, 1, bco), lambda e, i, j, k: (e, k, j)),
-        ]
-    else:
-        kernel = functools.partial(_kernel, n_k=n_k)
-        operands = (x, qt.packed, qt.scales, qt.zeros)
-        in_specs = [
-            pl.BlockSpec((1, bc, group), lambda e, i, j, k: (e, i, k)),
-            pl.BlockSpec((1, group // 2, bco), lambda e, i, j, k: (e, k, j)),
-            pl.BlockSpec((1, 1, bco), lambda e, i, j, k: (e, k, j)),
-            pl.BlockSpec((1, 1, bco), lambda e, i, j, k: (e, k, j)),
-        ]
+    in_specs = [pl.BlockSpec((1, bc, bci), lambda e, i, j, k: (e, i, k))]
+    operands = [x]
+    if a8:
+        in_specs.append(pl.BlockSpec((1, bc, 1), lambda e, i, j, k: (e, i, 0)))
+        operands.append(xs)
+    in_specs += weight_specs(gpk, group, bco, lambda e, i, j, k: (e, k, j),
+                             lead=(1,))
+    operands += [qt.packed, qt.scales, qt.zeros]
 
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_kernel, n_k=n_k, group=group, a8=a8),
         grid=(e, n_c, n_co, n_k),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bc, bco), lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, c_pad, co), out_dtype),
         scratch_shapes=[pltpu.VMEM((bc, bco), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -9,6 +9,11 @@ Beyond attention-only decoders the same flow serves hybrid SSM
 attention KV) and encoder-decoder (``--arch whisper-medium``: synthetic
 encoder frames per request, deduplicated read-only encoder pages).
 
+At full size (no ``--smoke``) the published widths are served in the
+config's own dtype; ``--num-layers N`` cuts depth only.  On a TPU the
+engine runs the Pallas W4A16 and paged-attention kernels (``chip_smoke.py``
+at the repo root drives this path once and checks it).
+
 ``--ptq-artifact DIR`` makes boot load-*or*-quantize: the first run saves the
 quantized pytree there; later runs deserialize it and skip calibration + the
 α search entirely (a config change invalidates the artifact via its hash).
@@ -16,22 +21,135 @@ quantized pytree there; later runs deserialize it and skip calibration + the
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
-from repro.configs.base import QuantConfig
+from repro.configs.base import ModelConfig, QuantConfig
 from repro.core.calibration import synthetic_calibration_set
 from repro.models import api
 from repro.serving.engine import Request, ServingEngine, load_or_quantize
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache across runs.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set (JAX reads it itself, so
+    nothing is set here); otherwise the cache lives at the fixed in-checkout
+    path ``<repo>/.jax_cache`` — the path is part of the cache key, so it
+    must not move between runs.  Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def model_config(arch: str, *, smoke: bool = False,
+                 num_layers: int | None = None, act_quant: str = "a16",
+                 quant: bool = True) -> ModelConfig:
+    """The served config: published widths, depth optionally cut to the
+    first ``num_layers`` layers (never a width).  Full-size configs keep
+    their own dtype — the PTQ math upcasts per tensor — while smoke configs
+    run PTQ in f32."""
+    cfg = get_config(arch, smoke=smoke).with_(act_quant=act_quant)
+    if num_layers is not None:
+        if not 1 <= num_layers <= cfg.num_layers:
+            raise ValueError(f"--num-layers {num_layers} outside "
+                             f"1..{cfg.num_layers} for {cfg.name}")
+        cfg = cfg.with_(num_layers=num_layers)
+    if quant and smoke:
+        cfg = cfg.with_(dtype="float32")
+    return cfg
+
+
+def describe_cut(cfg: ModelConfig, arch: str, smoke: bool = False) -> str:
+    """One line naming the depth cut and the (untouched) widths."""
+    full = get_config(arch, smoke=smoke).num_layers
+    return (f"[model] {cfg.name}: {cfg.num_layers} of {full} layers (depth "
+            f"cut only) d_model={cfg.d_model} heads={cfg.num_heads}/"
+            f"{cfg.num_kv_heads}kv head_dim={cfg.hdim} d_ff={cfg.d_ff} "
+            f"vocab={cfg.vocab_size} dtype={cfg.dtype}")
+
+
+def quantize_on_load(params, cfg: ModelConfig, group_size: int, *,
+                     artifact_dir=None, refresh: bool = False):
+    """SmoothQuant+ quantize-on-load (or a matching PTQ artifact's load).
+    Returns ``(qparams, report, mode, seconds)``; the seconds cover the
+    recipe up to quantized params resident on the device."""
+    from repro.core.apply import ptq_matches
+
+    calib = synthetic_calibration_set(cfg, n_seqs=2, seq_len=24)
+    qcfg = QuantConfig(group_size=group_size)
+    # a present-but-stale artifact still re-quantizes: label the boot by
+    # the path load_or_quantize will actually take
+    loaded = (artifact_dir is not None and not refresh
+              and ptq_matches(artifact_dir, cfg, qcfg))
+    t0 = time.perf_counter()
+    params, rep = load_or_quantize(params, cfg, calib, qcfg,
+                                   artifact_dir=artifact_dir, refresh=refresh)
+    jax.block_until_ready(params)
+    mode = "artifact-load" if loaded else "quantize-on-load"
+    return params, rep, mode, time.perf_counter() - t0
+
+
+def make_requests(cfg: ModelConfig, n: int, *, prompt_len: int = 10,
+                  max_tokens: int = 8, rate: float = 50.0,
+                  shared_prefix_len: int = 0, deadline_s=None,
+                  ttft_deadline_s=None, with_frames: bool = False) -> list:
+    """Seeded synthetic requests: Poisson arrivals at ``rate`` req/s on
+    the engine's clock (``time.perf_counter``), ``prompt_len`` random tokens
+    behind an optional shared prefix.  Enc-dec requests carry synthetic
+    encoder frames; every third repeats the first one's audio so the
+    exact-match encoder page cache has something to deduplicate."""
+    rng = np.random.default_rng(0)
+    # deadlines are wall-clock budgets from arrival: rebase the synthetic
+    # Poisson offsets onto the engine's clock, or every request would look
+    # minutes old at its first deadline check
+    base = time.perf_counter()
+    arrive = base + np.cumsum(rng.exponential(1.0 / rate, n))
+    sys_p = rng.integers(2, cfg.vocab_size, shared_prefix_len).astype(np.int32)
+
+    def frames(i):
+        if not with_frames:
+            return None
+        r = np.random.default_rng(1000 + (0 if i % 3 == 0 else i))
+        t = 6 + (0 if i % 3 == 0 else i % 5)
+        return (r.standard_normal((t, cfg.d_model)) * 0.1).astype(np.float32)
+
+    return [Request(uid=i,
+                    prompt=np.concatenate(
+                        [sys_p, rng.integers(2, cfg.vocab_size,
+                                             prompt_len).astype(np.int32)]),
+                    max_tokens=max_tokens, arrival_t=float(arrive[i]),
+                    deadline_s=deadline_s, ttft_deadline_s=ttft_deadline_s,
+                    frames=frames(i))
+            for i in range(n)]
+
+
+def serve_requests(eng: ServingEngine, reqs) -> tuple:
+    """Submit every request and drain the engine.  Returns
+    ``(accepted, seconds)``."""
+    t0 = time.perf_counter()
+    accepted = sum(eng.submit(r) for r in reqs)
+    eng.run_until_drained()
+    return accepted, time.perf_counter() - t0
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="codellama-7b")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--num-layers", type=int, default=None,
+                    help="serve only the first N layers (a depth cut; "
+                         "every width stays as published)")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=64)
@@ -97,29 +215,20 @@ def main(argv=None):
     if args.trace_out and args.no_metrics:
         ap.error("--trace-out needs metrics enabled (drop --no-metrics)")
 
-    cfg = get_config(args.arch, smoke=args.smoke).with_(
-        act_quant=args.act_quant)
-    if not args.no_quant:
-        cfg = cfg.with_(dtype="float32")  # PTQ math in f32 at smoke scale
+    enable_compile_cache()
+    cfg = model_config(args.arch, smoke=args.smoke, num_layers=args.num_layers,
+                       act_quant=args.act_quant, quant=not args.no_quant)
+    print(describe_cut(cfg, args.arch, args.smoke))
     params = api.init_model(jax.random.PRNGKey(0), cfg)
 
     if not args.no_quant:
         gs = args.group_size or (16 if args.smoke else 128)
-        calib = synthetic_calibration_set(cfg, n_seqs=2, seq_len=24)
-        t0 = time.time()
-        from repro.core.apply import ptq_matches
-        qcfg = QuantConfig(group_size=gs)
-        # a present-but-stale artifact still re-quantizes: label the boot by
-        # the path load_or_quantize will actually take
-        loaded = (args.ptq_artifact is not None and not args.ptq_refresh
-                  and ptq_matches(args.ptq_artifact, cfg, qcfg))
-        params, rep = load_or_quantize(params, cfg, calib, qcfg,
-                                       artifact_dir=args.ptq_artifact,
-                                       refresh=args.ptq_refresh)
-        mode = "artifact-load" if loaded else "quantize-on-load"
+        params, rep, mode, secs = quantize_on_load(
+            params, cfg, gs, artifact_dir=args.ptq_artifact,
+            refresh=args.ptq_refresh)
         print(f"[{mode}] alpha={rep.alpha:.2f} "
               f"{rep.fp_bytes/1e6:.1f}MB -> {rep.quant_bytes/1e6:.1f}MB "
-              f"in {time.time()-t0:.1f}s")
+              f"in {secs:.1f}s")
 
     fault_plan = None
     if args.chaos:
@@ -143,8 +252,10 @@ def main(argv=None):
     if args.prefix_cache == "on" and not prefix_cache:
         print(f"[note] token prefix cache disabled: {cfg.family} slots carry "
               f"state leaves {leaves}")
+    # backend "auto": the Pallas kernels on a TPU, the XLA references on
+    # any other host
     eng = ServingEngine(params, cfg, batch_size=args.batch_size,
-                        max_seq=args.max_seq, backend="xla",
+                        max_seq=args.max_seq, backend="auto",
                         page_size=args.page_size,
                         num_pages=args.num_pages,
                         prefill_mode=args.prefill_mode,
@@ -155,38 +266,13 @@ def main(argv=None):
                         fault_plan=fault_plan,
                         strict=not args.chaos,
                         metrics=not args.no_metrics)
-    rng = np.random.default_rng(0)
-    # deadlines are wall-clock budgets from arrival: rebase the synthetic
-    # Poisson offsets onto the engine's clock, or every request would look
-    # minutes old at its first deadline check
-    base = time.perf_counter()
-    arrive = base + np.cumsum(rng.exponential(1.0 / args.rate, args.requests))
-    sys_p = rng.integers(2, cfg.vocab_size,
-                         args.shared_prefix_len).astype(np.int32)
-
-    def frames(i):
-        # enc-dec requests carry synthetic encoder frames; every third
-        # request repeats the first one's audio so the exact-match encoder
-        # page cache has something to deduplicate
-        if not eng.has_enc:
-            return None
-        r = np.random.default_rng(1000 + (0 if i % 3 == 0 else i))
-        t = 6 + (0 if i % 3 == 0 else i % 5)
-        return (r.standard_normal((t, cfg.d_model)) * 0.1).astype(np.float32)
-
-    reqs = [Request(uid=i,
-                    prompt=np.concatenate(
-                        [sys_p,
-                         rng.integers(2, cfg.vocab_size, 10).astype(np.int32)]),
-                    max_tokens=args.max_tokens, arrival_t=float(arrive[i]),
-                    deadline_s=args.deadline_s,
-                    ttft_deadline_s=args.ttft_deadline_s,
-                    frames=frames(i))
-            for i in range(args.requests)]
-    t0 = time.perf_counter()
-    accepted = sum(eng.submit(r) for r in reqs)
-    eng.run_until_drained()
-    dt = time.perf_counter() - t0
+    reqs = make_requests(cfg, args.requests, max_tokens=args.max_tokens,
+                         rate=args.rate,
+                         shared_prefix_len=args.shared_prefix_len,
+                         deadline_s=args.deadline_s,
+                         ttft_deadline_s=args.ttft_deadline_s,
+                         with_frames=eng.has_enc)
+    accepted, dt = serve_requests(eng, reqs)
 
     # every stat line below renders engine.metrics_snapshot() — the single
     # structured source for operator reporting (benchmarks read it too)

@@ -120,7 +120,7 @@ def paged_supported(cfg: ModelConfig) -> Tuple[bool, str]:
 
 
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int):
-    """Per-layer KV pools ``[L, num_pages, page_size, ...]`` for the serving
+    """Per-layer KV pools ``[L, num_pages, H, page_size, ...]`` for the serving
     engine's block-table pager (``repro.serving.kv_cache``).  With
     ``cfg.kv_quant`` the pools are int8 plus per-row f32 scale pools.
     Enc-dec configs additionally carry the read-only encoder page pool
@@ -159,12 +159,12 @@ def quantize_raw_paged(raw, cfg: ModelConfig):
 def gather_pool_rows(pools, pages: jax.Array):
     """Gather whole pool pages for a slot swap-out.
 
-    ``pools`` leaves are ``[L, num_pages, page_size, ...]`` (any dtype — fp16
-    K/V, MLA latents, int8 codes and their f32 ``*_s`` scale leaves alike);
-    ``pages[n]`` are the pool page ids the slot owns.  Returns the matching
-    ``[L, n, page_size, ...]`` tree, ready for ``jax.device_get`` into a host
-    swap buffer.  jit re-specializes per page count; preemption is rare, so
-    the handful of traces is cheap."""
+    ``pools`` leaves are ``[L, num_pages, H, page_size, ...]`` (any dtype —
+    fp16 K/V, MLA latents, int8 codes and their f32 ``*_s`` scale leaves
+    alike); ``pages[n]`` are the pool page ids the slot owns.  Returns the
+    matching ``[L, n, H, page_size, ...]`` tree of whole pages, ready for
+    ``jax.device_get`` into a host swap buffer.  jit re-specializes per page
+    count; preemption is rare, so the handful of traces is cheap."""
     return jax.tree.map(lambda leaf: leaf[:, pages], pools)
 
 
@@ -172,7 +172,7 @@ def gather_pool_rows(pools, pages: jax.Array):
 def scatter_pool_rows(pools, rows, pages: jax.Array):
     """Inverse of :func:`gather_pool_rows`: write swapped-out rows back into
     freshly allocated pool pages (swap-in).  ``rows`` leaves are
-    ``[L, n, page_size, ...]``; dtypes already match the pools bit-for-bit
+    ``[L, n, H, page_size, ...]``; dtypes already match the pools bit-for-bit
     (the swap buffer stores raw codes + scales, never dequantized copies), so
     a resumed slot's cache is exactly what it was when preempted."""
     return jax.tree.map(

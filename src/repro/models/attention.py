@@ -176,29 +176,18 @@ def _chunk_positions(start_len: jax.Array, t: int) -> jax.Array:
 def _scatter_chunk(pool: Dict[str, jax.Array], updates: Dict[str, jax.Array],
                    table_rows: jax.Array, start_len: jax.Array,
                    chunk_len: jax.Array, kv_quant: bool):
-    """Scatter a ``[B, T, ...]`` chunk of raw KV rows into the paged pools at
+    """Scatter a ``[B, T, H, D]`` chunk of raw KV rows into the paged pools at
     logical positions ``start_len[b] + t`` (quantizing per row under
     ``kv_quant``).  Padded rows (``t >= chunk_len[b]``) land on the trash
     page, exactly like ``prefix_write_plan`` routes invalid rows."""
     b, t = next(iter(updates.values())).shape[:2]
-    ps = pool[next(iter(updates))].shape[1]
+    ps = pool[next(iter(updates))].shape[2]
     n_pages = table_rows.shape[1]
     pos = _chunk_positions(start_len, t)
     valid = jnp.arange(t, dtype=jnp.int32)[None, :] < chunk_len[:, None]
     lpage = jnp.minimum(pos // ps, n_pages - 1)
     pg = jnp.where(valid, table_rows[jnp.arange(b)[:, None], lpage], 0)
-    off = pos % ps
-    new_pool = dict(pool)
-    for name, rows in updates.items():
-        if kv_quant:
-            codes, scl = kv_quantize_rows(rows)
-            new_pool[name] = pool[name].at[pg, off].set(codes)
-            new_pool[name + "_s"] = pool[name + "_s"].at[pg, off].set(
-                scl.astype(pool[name + "_s"].dtype))
-        else:
-            new_pool[name] = pool[name].at[pg, off].set(
-                rows.astype(pool[name].dtype))
-    return new_pool
+    return _write_rows(pool, updates, pg, pos % ps, kv_quant)
 
 
 def gqa_prefill_chunk(
@@ -349,17 +338,84 @@ def init_gqa_cache(cfg: ModelConfig, batch: int, smax: int) -> Dict[str, jax.Arr
     }
 
 
+# ------------------------------------------------------------ page pools ---
+# Every paged KV leaf, GQA and MLA alike, is laid out per page as
+#   data  [num_pages, H, page_size, D]   (H = Hkv; 1 for MLA latents)
+#   scale [num_pages, H, page_size]      (int8 pools only)
+# so one page of one layer is one contiguous block, and the Pallas kernels'
+# page blocks end in (page_size, D) / (H, page_size): full extents or tile
+# multiples, which the TPU lowering requires (a [.., page_size, H, D] pool
+# would need a block of 1 on its head axis).  Rows enter and leave in
+# logical order as [..., H, *D]; only the helpers below know the layout.
+def init_page_pool(dims: Dict[str, int], num_pages: int, heads: int,
+                   page_size: int, dtype,
+                   kv_quant: bool) -> Dict[str, jax.Array]:
+    """Zeroed pool leaves ``{name: [NP, H, PS, D]}`` (+ ``name_s`` f32
+    ``[NP, H, PS]`` scale leaves and int8 codes under ``kv_quant``)."""
+    pool = {}
+    for name, d in dims.items():
+        shp = (num_pages, heads, page_size)
+        pool[name] = jnp.zeros((*shp, d), jnp.int8 if kv_quant else dtype)
+        if kv_quant:
+            pool[name + "_s"] = jnp.zeros(shp, jnp.float32)
+    return pool
+
+
+def scatter_rows(leaf: jax.Array, page: jax.Array, off: jax.Array,
+                 rows: jax.Array) -> jax.Array:
+    """Write rows ``[*I, H, *D]`` into ``leaf`` at pool pages ``page[*I]``,
+    in-page offsets ``off[*I]``."""
+    return leaf.at[page, :, off].set(rows.astype(leaf.dtype))
+
+
 def gather_pages(pool: jax.Array, table_rows: jax.Array) -> jax.Array:
-    """``pool[num_pages, page_size, ...]`` + page table ``table_rows[B, P]``
-    → dense ``[B, P*page_size, ...]`` rows in logical-position order.
+    """``pool[num_pages, H, page_size, ...]`` + page table ``table_rows[B, P]``
+    → dense ``[B, P*page_size, H, ...]`` rows in logical-position order.
 
     This is the jnp *reference* gather (``paged_attn_impl="gather"``): it
     materializes the full trash-padded table in HBM every step.  The Pallas
     paged-attention kernel (``kernels/paged_attention.py``) indexes the pool
     inside the grid instead and never builds this array.  Re-exported by
     ``serving.kv_cache`` for the pager tests."""
-    g = pool[table_rows]
+    g = jnp.swapaxes(pool[table_rows], 2, 3)        # [B, P, PS, H, ...]
     return g.reshape(g.shape[0], g.shape[1] * g.shape[2], *g.shape[3:])
+
+
+def rows_to_pages(rows: jax.Array, page_size: int) -> jax.Array:
+    """Logical rows ``[..., n·page_size, H, D]`` → whole pages
+    ``[..., n, H, page_size, D]`` (the inverse of :func:`gather_pages`'
+    flattening), ready for a whole-page scatter."""
+    *lead, s, h, d = rows.shape
+    pages = rows.reshape(*lead, s // page_size, page_size, h, d)
+    return jnp.swapaxes(pages, -3, -2)
+
+
+def _write_rows(pool: Dict[str, jax.Array], rows: Dict[str, jax.Array],
+                pg: jax.Array, off: jax.Array,
+                kv_quant: bool) -> Dict[str, jax.Array]:
+    """Scatter ``{name: [*I, H, D]}`` rows at pool pages ``pg[*I]`` /
+    offsets ``off[*I]``, quantizing per row (codes + ``name_s`` scales)
+    under ``kv_quant``."""
+    new_pool = dict(pool)
+    for name, r in rows.items():
+        if kv_quant:
+            codes, scl = kv_quantize_rows(r)
+            new_pool[name] = scatter_rows(pool[name], pg, off, codes)
+            new_pool[name + "_s"] = scatter_rows(pool[name + "_s"], pg, off,
+                                                 scl)
+        else:
+            new_pool[name] = scatter_rows(pool[name], pg, off, r)
+    return new_pool
+
+
+def _write_token(pool: Dict[str, jax.Array], rows: Dict[str, jax.Array],
+                 table_rows: jax.Array, write_pos: jax.Array,
+                 kv_quant: bool) -> Dict[str, jax.Array]:
+    """Write one decode token's rows ``{name: [B, H, D]}`` at logical
+    position ``write_pos[B]``."""
+    page_size = pool[next(iter(rows))].shape[2]
+    pg = table_rows[jnp.arange(table_rows.shape[0]), write_pos // page_size]
+    return _write_rows(pool, rows, pg, write_pos % page_size, kv_quant)
 
 
 def _resolve_paged_impl(cfg: ModelConfig, backend: str) -> str:
@@ -382,7 +438,7 @@ def gqa_decode_paged(
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One-token decode against a *paged* pool.
 
-    ``pool``: ``{"k"/"v": [num_pages, page_size, Hkv, Dh]}`` shared across
+    ``pool``: ``{"k"/"v": [num_pages, Hkv, page_size, Dh]}`` shared across
     slots; ``table_rows[B, P]`` maps each slot's logical pages to pool pages
     (unused entries point at the trash page); ``write_pos[B]`` is the logical
     position the new token lands at.  Rows are gathered back into logical
@@ -393,26 +449,10 @@ def gqa_decode_paged(
     assert t == 1, "decode processes one token"
     h, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.hdim
     q, k, v = _qkv(p, x, positions, cfg, backend)
-    page_size = pool["k"].shape[1]
-    bidx = jnp.arange(b)
-    pg = table_rows[bidx, write_pos // page_size]           # [B] pool page ids
-    off = write_pos % page_size
     # distinct slots own distinct pages → scatter indices collide only for
     # idle slots, whose table rows all point at the trash page
-    if cfg.kv_quant:
-        kq, ks = kv_quantize_rows(k[:, 0])
-        vq, vs = kv_quantize_rows(v[:, 0])
-        new_pool = {
-            "k": pool["k"].at[pg, off].set(kq),
-            "v": pool["v"].at[pg, off].set(vq),
-            "k_s": pool["k_s"].at[pg, off].set(ks.astype(pool["k_s"].dtype)),
-            "v_s": pool["v_s"].at[pg, off].set(vs.astype(pool["v_s"].dtype)),
-        }
-    else:
-        new_pool = {
-            "k": pool["k"].at[pg, off].set(k[:, 0]),
-            "v": pool["v"].at[pg, off].set(v[:, 0]),
-        }
+    new_pool = _write_token(pool, {"k": k[:, 0], "v": v[:, 0]}, table_rows,
+                            write_pos, cfg.kv_quant)
     qh = q.reshape(b, hkv, h // hkv, dh)
     scale = dh ** -0.5
     impl = _resolve_paged_impl(cfg, backend)
@@ -443,18 +483,11 @@ def gqa_decode_paged(
 
 
 def init_gqa_page_pool(cfg: ModelConfig, num_pages: int, page_size: int):
-    hkv, dh = cfg.num_kv_heads, cfg.hdim
-    shp = (num_pages, page_size, hkv, dh)
-    if cfg.kv_quant:
-        # int8 rows + per-(position, head) f32 scale pool: halves KV page
-        # bytes on the memory-bound decode path (scales are Dh× smaller)
-        return {
-            "k": jnp.zeros(shp, jnp.int8),
-            "v": jnp.zeros(shp, jnp.int8),
-            "k_s": jnp.zeros((num_pages, page_size, hkv), jnp.float32),
-            "v_s": jnp.zeros((num_pages, page_size, hkv), jnp.float32),
-        }
-    return {"k": jnp.zeros(shp, cfg.jdtype), "v": jnp.zeros(shp, cfg.jdtype)}
+    # int8 rows + per-(head, position) f32 scale pool under kv_quant: halves
+    # KV page bytes on the memory-bound decode path (scales are Dh× smaller)
+    return init_page_pool({"k": cfg.hdim, "v": cfg.hdim}, num_pages,
+                          cfg.num_kv_heads, page_size, cfg.jdtype,
+                          cfg.kv_quant)
 
 
 def kv_quantize_rows(x: jax.Array):
@@ -562,7 +595,8 @@ def mla_prefill_chunk(
     positions = _chunk_positions(start_len, t)
     q_nope, q_pe = _mla_q(p, x, positions, cfg, backend)
     ckv_suf, kpe_suf = _mla_latent(p, x, positions, cfg, backend)
-    new_pool = _scatter_chunk(pool, {"ckv": ckv_suf, "kpe": kpe_suf},
+    new_pool = _scatter_chunk(pool, {"ckv": ckv_suf[:, :, None],
+                                     "kpe": kpe_suf[:, :, None]},
                               table_rows, start_len, chunk_len, cfg.kv_quant)
     q_lat = _mla_absorb_q_lat(
         p, q_nope.reshape(b * t, h, m.qk_nope_head_dim), cfg, backend
@@ -585,10 +619,10 @@ def mla_prefill_chunk(
         # suffix raw — same masks as the kernel grid
         pckv = _dequant_pages(gather_pages(new_pool["ckv"], table_rows),
                               gather_pages(new_pool["ckv_s"], table_rows)
-                              if cfg.kv_quant else None)
+                              if cfg.kv_quant else None)[:, :, 0]
         pkpe = _dequant_pages(gather_pages(new_pool["kpe"], table_rows),
                               gather_pages(new_pool["kpe_s"], table_rows)
-                              if cfg.kv_quant else None)
+                              if cfg.kv_quant else None)[:, :, 0]
         s = pckv.shape[1]
         ckv_all = jnp.concatenate(
             [pckv.astype(jnp.float32), ckv_suf.astype(jnp.float32)], axis=1)
@@ -718,34 +752,18 @@ def mla_decode_paged(
     write_pos: jax.Array, cfg: ModelConfig, *, backend: str = "auto"
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Absorbed-form decode against a paged latent pool
-    (``{"ckv": [NP,PS,r], "kpe": [NP,PS,dr]}``); see :func:`gqa_decode_paged`
-    for the page-table convention."""
+    (``{"ckv": [NP,1,PS,r], "kpe": [NP,1,PS,dr]}``); see
+    :func:`gqa_decode_paged` for the page-table convention."""
     b, t, _ = x.shape
     assert t == 1
     m = cfg.mla
     h = cfg.num_heads
     q_nope, q_pe = _mla_q(p, x, positions, cfg, backend)
     ckv_new, kpe_new = _mla_latent(p, x, positions, cfg, backend)
-    page_size = pool["ckv"].shape[1]
-    bidx = jnp.arange(b)
-    pg = table_rows[bidx, write_pos // page_size]
-    off = write_pos % page_size
-    if cfg.kv_quant:
-        cq, cs = kv_quantize_rows(ckv_new[:, 0])            # [B,r] → per-row
-        kq, ks = kv_quantize_rows(kpe_new[:, 0])
-        new_pool = {
-            "ckv": pool["ckv"].at[pg, off].set(cq),
-            "kpe": pool["kpe"].at[pg, off].set(kq),
-            "ckv_s": pool["ckv_s"].at[pg, off].set(
-                cs.astype(pool["ckv_s"].dtype)),
-            "kpe_s": pool["kpe_s"].at[pg, off].set(
-                ks.astype(pool["kpe_s"].dtype)),
-        }
-    else:
-        new_pool = {
-            "ckv": pool["ckv"].at[pg, off].set(ckv_new[:, 0]),
-            "kpe": pool["kpe"].at[pg, off].set(kpe_new[:, 0]),
-        }
+    # latents carry the pools' unit head axis: [B, 1, r]
+    new_pool = _write_token(pool, {"ckv": ckv_new[:, :1],
+                                   "kpe": kpe_new[:, :1]},
+                            table_rows, write_pos, cfg.kv_quant)
     impl = _resolve_paged_impl(cfg, backend)
     scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
 
@@ -763,14 +781,14 @@ def mla_decode_paged(
         out = _mla_absorb_out(p, o_lat, cfg, kernel_backend).reshape(
             b, 1, h * m.v_head_dim)
     else:
-        ckv = gather_pages(new_pool["ckv"], table_rows)
-        kpe = gather_pages(new_pool["kpe"], table_rows)
-        if cfg.kv_quant:
-            # XLA reference: dequantize the gathered latent rows in-flight
-            ckv = ckv.astype(jnp.float32) * gather_pages(
-                new_pool["ckv_s"], table_rows).astype(jnp.float32)[..., None]
-            kpe = kpe.astype(jnp.float32) * gather_pages(
-                new_pool["kpe_s"], table_rows).astype(jnp.float32)[..., None]
+        # XLA reference: dense gather (+ in-flight dequant of int8 rows) of
+        # the latent pages, head axis dropped
+        ckv = _dequant_pages(gather_pages(new_pool["ckv"], table_rows),
+                             gather_pages(new_pool["ckv_s"], table_rows)
+                             if cfg.kv_quant else None)[:, :, 0]
+        kpe = _dequant_pages(gather_pages(new_pool["kpe"], table_rows),
+                             gather_pages(new_pool["kpe_s"], table_rows)
+                             if cfg.kv_quant else None)[:, :, 0]
         valid = jnp.arange(ckv.shape[1])[None, :] <= write_pos[:, None]
         out = _mla_absorbed_attend(p, q_nope, q_pe, ckv, kpe, valid, cfg,
                                    backend)
@@ -780,18 +798,8 @@ def mla_decode_paged(
 
 def init_mla_page_pool(cfg: ModelConfig, num_pages: int, page_size: int):
     m = cfg.mla
-    if cfg.kv_quant:
-        return {
-            "ckv": jnp.zeros((num_pages, page_size, m.kv_lora_rank), jnp.int8),
-            "kpe": jnp.zeros((num_pages, page_size, m.qk_rope_head_dim),
-                             jnp.int8),
-            "ckv_s": jnp.zeros((num_pages, page_size), jnp.float32),
-            "kpe_s": jnp.zeros((num_pages, page_size), jnp.float32),
-        }
-    return {
-        "ckv": jnp.zeros((num_pages, page_size, m.kv_lora_rank), cfg.jdtype),
-        "kpe": jnp.zeros((num_pages, page_size, m.qk_rope_head_dim), cfg.jdtype),
-    }
+    return init_page_pool({"ckv": m.kv_lora_rank, "kpe": m.qk_rope_head_dim},
+                          num_pages, 1, page_size, cfg.jdtype, cfg.kv_quant)
 
 
 def init_mla_cache(cfg: ModelConfig, batch: int, smax: int) -> Dict[str, jax.Array]:

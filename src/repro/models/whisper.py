@@ -264,7 +264,7 @@ def init_whisper_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int):
     pools = [A.init_gqa_page_pool(cfg, num_pages, page_size)
              for _ in range(cfg.num_layers)]
     hkv, dh = cfg.num_kv_heads, cfg.hdim
-    eshp = (cfg.num_layers, num_pages, page_size, hkv, dh)
+    eshp = (cfg.num_layers, num_pages, hkv, page_size, dh)
     return {
         "layers": jax.tree.map(lambda *xs: jnp.stack(xs), *pools),
         "enc": {
@@ -297,12 +297,9 @@ def _gather_enc(exk, exv, enc_table, enc_len):
     order.  ``enc_len`` is clamped to >= 1 so rows whose slots hold no
     encoder pages (trash table) still see one valid (zero) row — masked
     softmax stays finite."""
-    b, pe = enc_table.shape
-    ps = exk.shape[1]
-    s = pe * ps
-    hkv, dh = exk.shape[-2], exk.shape[-1]
-    xk = exk[enc_table].reshape(b, s, hkv, dh)
-    xv = exv[enc_table].reshape(b, s, hkv, dh)
+    xk = A.gather_pages(exk, enc_table)
+    xv = A.gather_pages(exv, enc_table)
+    s = xk.shape[1]
     valid = jnp.arange(s, dtype=jnp.int32)[None, :] < jnp.maximum(enc_len, 1)[:, None]
     return xk, xv, valid
 

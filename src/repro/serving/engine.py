@@ -90,6 +90,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, QuantConfig
 from repro.models import api
+from repro.models.attention import rows_to_pages
 from repro.serving import kv_cache as KV
 from repro.serving.faults import (FaultPlan, SimulatedDeviceError,
                                   TransientFault, corrupt_host_image)
@@ -983,10 +984,9 @@ class ServingEngine:
             s = int(kv["xk"].shape[2])
             pad = npg * self.PS - s
             rows = jax.tree.map(
-                lambda a: jnp.pad(a[:, 0], ((0, 0), (0, pad), (0, 0),
-                                            (0, 0)))
-                             .reshape(a.shape[0], npg, self.PS,
-                                      a.shape[3], a.shape[4]),
+                lambda a: rows_to_pages(
+                    jnp.pad(a[:, 0], ((0, 0), (0, pad), (0, 0), (0, 0))),
+                    self.PS),
                 kv)
             self.pools = {**self.pools, "enc": api.scatter_pool_rows(
                 self.pools["enc"], rows, jnp.asarray(pages, jnp.int32))}

@@ -1,13 +1,14 @@
 """Paged KV cache: fixed-size pages + per-slot page tables (the vLLM idea).
 
 The decode cache is a single device-resident *pool* per layer —
-``k[num_pages, page_size, Hkv, Dh]`` — instead of one contiguous
+``k[num_pages, Hkv, page_size, Dh]`` — instead of one contiguous
 ``[B, Smax, ...]`` slab.  A host-side :class:`PagePool` hands out pages to
 slots on admission and reclaims them when a request finishes, so cache memory
 scales with *live tokens*, not ``batch_size × max_seq``.
 
 Logical position ``t`` of slot ``s`` lives at
-``pool[table[s, t // page_size], t % page_size]``.
+``pool[table[s, t // page_size], :, t % page_size]`` (the page layout
+lives with the attention code: ``models.attention.init_page_pool``).
 
 Page 0 is reserved as a **trash page**: every unused page-table entry points
 at it, so idle slot rows in the batched decode step scatter their garbage
@@ -509,13 +510,11 @@ def prefix_write_plan(lens: np.ndarray, table_rows: np.ndarray,
 def write_prefix(pools: Any, kv: Any, page: jax.Array, off: jax.Array) -> Any:
     """Scatter raw prefix KV into the pools.
 
-    ``pools`` leaves are ``[L, num_pages, page_size, ...]``; ``kv`` leaves are
-    the matching raw prefill caches ``[L, n, T, ...]``; ``page``/``off`` are
-    ``[n, T]`` from :func:`prefix_write_plan`.
+    ``pools`` leaves are ``[L, num_pages, H, page_size, ...]``; ``kv`` leaves
+    are the matching raw prefill caches ``[L, n, T, H, ...]``;
+    ``page``/``off`` are ``[n, T]`` from :func:`prefix_write_plan`.
     """
-    def put(pool, new):
-        return pool.at[:, page, off].set(new.astype(pool.dtype))
-
+    put = jax.vmap(lambda pool, new: scatter_rows(pool, page, off, new))
     return jax.tree.map(put, pools, kv)
 
 
@@ -605,4 +604,4 @@ def assert_live_tables(table, write_pos, page_size: int, active, *,
 # canonical page gather lives next to the attention decode paths that
 # consume it (the jnp reference for the Pallas paged-attention kernel);
 # re-exported here so pager users/tests need only this module
-from repro.models.attention import gather_pages  # noqa: E402,F401
+from repro.models.attention import gather_pages, scatter_rows  # noqa: E402,F401

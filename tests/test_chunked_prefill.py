@@ -113,7 +113,7 @@ def test_gqa_chunk_kernel_ignores_dead_page_garbage():
     for bi, start in enumerate(STARTS):
         for pos in range(start):                  # ...except live prefix rows
             dead[tbl[bi, pos // ps], pos % ps] = False
-    mask = jnp.asarray(dead)[:, :, None, None]
+    mask = jnp.asarray(dead)[:, None, :, None]    # pools are [NP, H, PS, D]
     poisoned = dict(pool, k=jnp.where(mask, 1e4, pool["k"]),
                     v=jnp.where(mask, 1e4, pool["v"]))
     y1, _ = A.gqa_prefill_chunk(p, x, poisoned, table, starts, chunks, impl,

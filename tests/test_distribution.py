@@ -17,15 +17,9 @@ from repro.sharding import rules
 
 
 def _mesh16():
-    # 16x16 spec-building only needs axis names/sizes, not real devices:
-    # use a tiny abstract mesh via jax.sharding.AbstractMesh.  Its ctor
-    # flipped between ((name, size), ...) pairs and (sizes, names) across
-    # jax releases — accept whichever this version ships.
+    # 16x16 spec-building only needs axis names/sizes, not real devices
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh((("data", 16), ("model", 16)))
-    except TypeError:
-        return AbstractMesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_param_specs_dense_tp():

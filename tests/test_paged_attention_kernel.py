@@ -208,21 +208,3 @@ def test_stale_table_guard_raises_on_freed_active_slot():
         KV.assert_live_tables(pool.table(), write_pos, 4, active)
     # idle slots pointing at trash are fine
     KV.assert_live_tables(pool.table(), write_pos, 4, [False, False])
-
-
-@pytest.mark.slow
-def test_gqa_paged_kernel_compiles_on_tpu():
-    """Real-TPU compile/execute smoke (skipped on CPU CI; `-m slow` on TPU)."""
-    if jax.default_backend() != "tpu":
-        pytest.skip("needs a real TPU")
-    cfg = get_config("codellama-7b", smoke=True)
-    b, ps, pages = 2, 16, 2
-    p = A.init_gqa(jax.random.PRNGKey(0), cfg)
-    _, table = _paged_state(b, pages, ps)
-    pool = _fill(A.init_gqa_page_pool(cfg, 1 + b * pages, ps), seed=1)
-    x = jax.random.normal(jax.random.PRNGKey(2), (b, 1, cfg.d_model), cfg.jdtype)
-    wp = jnp.asarray([3, 17])
-    y, _ = A.gqa_decode_paged(p, x, wp[:, None], pool, table, wp,
-                              cfg.with_(paged_attn_impl="pallas"),
-                              backend="pallas")
-    assert np.isfinite(np.asarray(y, np.float32)).all()

@@ -1,0 +1,146 @@
+"""Every main-path Pallas kernel compiles for a TPU v5e at published widths.
+
+Interpret mode (the rest of the suite) never checks the TPU lowering's block
+rules or fast-memory limits, so each kernel is compiled here ahead of time
+for one chip of a *described* ``v5e:2x2`` topology — no chip needed, about a
+second per kernel.  Widths: StarCoder2-15B (d_model 6144, d_ff 24576, 48
+query / 4 KV heads of 128, group size 128) for the dense GEMMs and GQA
+attention; DeepSeek-V2 (128 heads, latent rank 512, rope dim 64, 160
+experts of 1536 over d_model 5120) for MLA and the grouped GEMM.
+"""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.quantize import QuantizedTensor
+
+W4 = importlib.import_module("repro.kernels.w4a16_matmul")
+W4G = importlib.import_module("repro.kernels.w4a16_grouped")
+PA = importlib.import_module("repro.kernels.paged_attention")
+FA = importlib.import_module("repro.kernels.flash_attention")
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+D, FF, HQ, HKV, DH, G = 6144, 24576, 48, 4, 128, 128
+MLA_H, MLA_R, MLA_DR = 128, 512, 64
+PS, PAGES, B = 16, 16, 8
+NP = 1 + B * PAGES
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # compiles for a described chip are written to the persistent cache but
+    # can never be read back without one: keep the cache off around them
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes):
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _qt(sds, *lead, ci, co):
+    return QuantizedTensor(sds((*lead, ci // 2, co), jnp.uint8),
+                           sds((*lead, ci // G, co), BF16),
+                           sds((*lead, ci // G, co), BF16))
+
+
+@pytest.fixture(scope="module")
+def sds(one_chip):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+@pytest.mark.parametrize("ci,co", [(D, FF), (FF, D)])
+@pytest.mark.parametrize("t", [8, 512])      # decode batch, prefill chunk
+@pytest.mark.parametrize("act", ["a16", "a8"])
+def test_w4a16_matmul(sds, act, t, ci, co):
+    _compile(lambda x, w: W4.w4a16_matmul(x, w, act=act),
+             sds((t, ci), BF16), _qt(sds, ci=ci, co=co))
+
+
+@pytest.mark.parametrize("e,c,d,f", [
+    (MLA_H, B, 128, MLA_R),      # MLA absorbed query, heads as experts
+    (160, 64, 5120, 1536),       # routed-expert FFN up-projection
+])
+@pytest.mark.parametrize("act", ["a16", "a8"])
+def test_w4a16_grouped(sds, act, e, c, d, f):
+    _compile(lambda x, w: W4G.w4a16_grouped_matmul(x, w, act=act),
+             sds((e, c, d), BF16), _qt(sds, e, ci=d, co=f))
+
+
+def _pool(sds, heads, dim, quant):
+    data = sds((NP, heads, PS, dim), I8 if quant else BF16)
+    scale = (sds((NP, heads, PS), F32),) if quant else ()
+    return data, scale
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_gqa_paged_decode(sds, quant):
+    k, ks = _pool(sds, HKV, DH, quant)
+    _compile(lambda q, k, v, t, n, *s: PA.gqa_paged_attention(
+                 q, k, v, t, n, *s, sm_scale=DH ** -0.5),
+             sds((B, HKV, HQ // HKV, DH), BF16), k, k,
+             sds((B, PAGES), I32), sds((B,), I32), *ks, *ks)
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_gqa_paged_prefill(sds, quant):
+    t = 512
+    k, ks = _pool(sds, HKV, DH, quant)
+    _compile(lambda q, ksf, vsf, k, v, tb, pf, cl, *s: PA.gqa_paged_prefill(
+                 q, ksf, vsf, k, v, tb, pf, cl, *s, sm_scale=DH ** -0.5),
+             sds((1, t, HKV, HQ // HKV, DH), BF16), sds((1, t, HKV, DH), BF16),
+             sds((1, t, HKV, DH), BF16), k, k, sds((1, PAGES), I32),
+             sds((1,), I32), sds((1,), I32), *ks, *ks)
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_mla_paged_decode(sds, quant):
+    c, cs = _pool(sds, 1, MLA_R, quant)
+    p, pss = _pool(sds, 1, MLA_DR, quant)
+    _compile(lambda ql, qp, c, p, t, n, *s: PA.mla_paged_attention(
+                 ql, qp, c, p, t, n, *s, sm_scale=0.1),
+             sds((B, MLA_H, MLA_R), F32), sds((B, MLA_H, MLA_DR), BF16), c, p,
+             sds((B, PAGES), I32), sds((B,), I32), *cs, *pss)
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_mla_paged_prefill(sds, quant):
+    t = 64
+    c, cs = _pool(sds, 1, MLA_R, quant)
+    p, pss = _pool(sds, 1, MLA_DR, quant)
+    _compile(lambda ql, qp, csf, psf, c, p, tb, pf, cl, *s:
+             PA.mla_paged_prefill(ql, qp, csf, psf, c, p, tb, pf, cl, *s,
+                                  sm_scale=0.1),
+             sds((1, t, MLA_H, MLA_R), F32), sds((1, t, MLA_H, MLA_DR), BF16),
+             sds((1, t, MLA_R), BF16), sds((1, t, MLA_DR), BF16), c, p,
+             sds((1, PAGES), I32), sds((1,), I32), sds((1,), I32), *cs, *pss)
+
+
+def test_flash_attention(sds):
+    t = 2048
+    _compile(lambda q, k, v: FA.flash_attention(q, k, v, causal=True),
+             sds((1, t, HQ, DH), BF16), sds((1, t, HKV, DH), BF16),
+             sds((1, t, HKV, DH), BF16))
