@@ -376,34 +376,31 @@ class ServingEngine:
         # instead of allocating a second full pool every decoded token.
         # The launch signature follows the config's state leaves — hybrid
         # threads the fixed-rows tree (donated too) plus an active mask,
-        # enc-dec threads the encoder page table + valid lengths.
+        # enc-dec threads the encoder page table + valid lengths.  Each step
+        # program is a named function, so a device trace names its module
+        # (``jit_decode_step``, ``jit_prefill_chunk_step``).
         if self.has_fixed:
-            self._decode = jax.jit(
-                lambda p, c, fixed, tok, pos, table, active:
-                    api.decode_paged_fn(
-                        p, {"token": tok, "position": pos}, c, table, cfg,
-                        backend=backend, fixed=fixed, active=active),
-                donate_argnums=(1, 2),
-            )
-        elif self.has_enc:
-            self._decode = jax.jit(
-                lambda p, c, tok, pos, table, enc_table, enc_len:
-                    api.decode_paged_fn(
-                        p, {"token": tok, "position": pos}, c, table, cfg,
-                        backend=backend, enc_table=enc_table,
-                        enc_len=enc_len),
-                donate_argnums=(1,),
-            )
-            self._encode = jax.jit(
-                lambda p, fr: api.encode_kv_fn(p, fr, cfg, backend=backend))
-        else:
-            self._decode = jax.jit(
-                lambda p, c, tok, pos, table: api.decode_paged_fn(
+            def decode_step(p, c, fixed, tok, pos, table, active):
+                return api.decode_paged_fn(
                     p, {"token": tok, "position": pos}, c, table, cfg,
-                    backend=backend
-                ),
-                donate_argnums=(1,),
-            )
+                    backend=backend, fixed=fixed, active=active)
+            self._decode = jax.jit(decode_step, donate_argnums=(1, 2))
+        elif self.has_enc:
+            def decode_step(p, c, tok, pos, table, enc_table, enc_len):
+                return api.decode_paged_fn(
+                    p, {"token": tok, "position": pos}, c, table, cfg,
+                    backend=backend, enc_table=enc_table, enc_len=enc_len)
+
+            def encode_step(p, fr):
+                return api.encode_kv_fn(p, fr, cfg, backend=backend)
+            self._decode = jax.jit(decode_step, donate_argnums=(1,))
+            self._encode = jax.jit(encode_step)
+        else:
+            def decode_step(p, c, tok, pos, table):
+                return api.decode_paged_fn(
+                    p, {"token": tok, "position": pos}, c, table, cfg,
+                    backend=backend)
+            self._decode = jax.jit(decode_step, donate_argnums=(1,))
         # joint length-bucketed chunk prefill: each row is one [blen] prompt
         # chunk at logical positions start_len[r] + t; KV scatters into the
         # pages and attention reads every earlier token (cached prefix and
@@ -412,41 +409,37 @@ class ServingEngine:
         # trace count O(log max_seq).  Pools donated: the chunk's output
         # cache aliases the input buffers.
         if self.has_fixed:
-            self._prefill_chunk = jax.jit(
-                lambda p, toks, last_idx, starts, lens, table, pools, fixed,
-                       slots:
-                    api.prefill_chunk_fn(
-                        p, {"tokens": toks}, pools, table, starts, lens, cfg,
-                        backend=backend, last_idx=last_idx, fixed=fixed,
-                        slots=slots),
-                donate_argnums=(6, 7),
-            )
+            def prefill_chunk_step(p, toks, last_idx, starts, lens, table,
+                                   pools, fixed, slots):
+                return api.prefill_chunk_fn(
+                    p, {"tokens": toks}, pools, table, starts, lens, cfg,
+                    backend=backend, last_idx=last_idx, fixed=fixed,
+                    slots=slots)
+
             # fresh admission starts from zero SSM state (the previous
             # occupant's rows are stale, not trash-maskable like KV pages)
-            self._fixed_zero = jax.jit(
-                lambda f, slot: jax.tree.map(
-                    lambda a: a.at[:, slot].set(0), f),
-                donate_argnums=(0,),
-            )
+            def fixed_zero(f, slot):
+                return jax.tree.map(lambda a: a.at[:, slot].set(0), f)
+            self._prefill_chunk = jax.jit(prefill_chunk_step,
+                                          donate_argnums=(6, 7))
+            self._fixed_zero = jax.jit(fixed_zero, donate_argnums=(0,))
         elif self.has_enc:
-            self._prefill_chunk = jax.jit(
-                lambda p, toks, last_idx, starts, lens, table, pools,
-                       enc_table, enc_len:
-                    api.prefill_chunk_fn(
-                        p, {"tokens": toks}, pools, table, starts, lens, cfg,
-                        backend=backend, last_idx=last_idx,
-                        enc_table=enc_table, enc_len=enc_len),
-                donate_argnums=(6,),
-            )
+            def prefill_chunk_step(p, toks, last_idx, starts, lens, table,
+                                   pools, enc_table, enc_len):
+                return api.prefill_chunk_fn(
+                    p, {"tokens": toks}, pools, table, starts, lens, cfg,
+                    backend=backend, last_idx=last_idx,
+                    enc_table=enc_table, enc_len=enc_len)
+            self._prefill_chunk = jax.jit(prefill_chunk_step,
+                                          donate_argnums=(6,))
         else:
-            self._prefill_chunk = jax.jit(
-                lambda p, toks, last_idx, starts, lens, table, pools:
-                    api.prefill_chunk_fn(
-                        p, {"tokens": toks}, pools, table, starts, lens, cfg,
-                        backend=backend, last_idx=last_idx
-                    ),
-                donate_argnums=(6,),
-            )
+            def prefill_chunk_step(p, toks, last_idx, starts, lens, table,
+                                   pools):
+                return api.prefill_chunk_fn(
+                    p, {"tokens": toks}, pools, table, starts, lens, cfg,
+                    backend=backend, last_idx=last_idx)
+            self._prefill_chunk = jax.jit(prefill_chunk_step,
+                                          donate_argnums=(6,))
         self._sample = jax.jit(sample_per_slot)
 
     # ----------------------------------------------------- observability ---
@@ -1015,72 +1008,86 @@ class ServingEngine:
             self._charge_retry(items[0][0], "prefill launch faulted")
             return 0
         worked = 0
-        for bkt in self.sched.plan_chunks(items):
-            n, blen = len(bkt.slots), bkt.pad_len
-            starts = np.asarray(bkt.starts, np.int32)
-            lens = np.asarray(bkt.lens, np.int32)
-            toks = np.zeros((n, blen), np.int32)
-            for r, slot in enumerate(bkt.slots):
-                req = self.slots[slot]
-                toks[r, : lens[r]] = req.prompt[starts[r]: starts[r] + lens[r]]
-            table = jnp.asarray(self.pager.table()[bkt.slots])
-            if self.has_fixed:
-                logits, self.pools, self.fixed = self._prefill_chunk(
-                    self.params, jnp.asarray(toks), jnp.asarray(lens - 1),
-                    jnp.asarray(starts), jnp.asarray(lens), table,
-                    self.pools, self.fixed,
-                    jnp.asarray(bkt.slots, jnp.int32))
-            elif self.has_enc:
-                logits, self.pools = self._prefill_chunk(
-                    self.params, jnp.asarray(toks), jnp.asarray(lens - 1),
-                    jnp.asarray(starts), jnp.asarray(lens), table,
-                    self.pools,
-                    jnp.asarray(self.pager.table("enc")[bkt.slots]),
-                    jnp.asarray(self.enc_len[list(bkt.slots)]))
-            else:
-                logits, self.pools = self._prefill_chunk(
-                    self.params, jnp.asarray(toks), jnp.asarray(lens - 1),
-                    jnp.asarray(starts), jnp.asarray(lens), table,
-                    self.pools)
-            finals = [self.slots[s] if f else None
-                      for s, f in zip(bkt.slots, bkt.final)]
-            if any(bkt.final):
-                self.key, sk = jax.random.split(self.key)
-                temps = jnp.asarray(
-                    [r.temperature if r else 0.0 for r in finals], jnp.float32)
-                firsts = np.asarray(
-                    self._sample_reqs(logits, sk, temps, finals))
-                now = self._clock()
-            for r, slot in enumerate(bkt.slots):
-                self.pos[slot] += int(lens[r])
-                self.stats.prefilled_tokens += int(lens[r])
-                worked += 1
-                if self._obs:
-                    self.trace.note_chunk(slot, self.slots[slot].uid,
-                                          int(lens[r]))
-                if bkt.final[r]:
+        phase = self.trace.phase
+        with phase("plan_chunks"):
+            buckets = self.sched.plan_chunks(items)
+        for bkt in buckets:
+            with phase("prefill_inputs"):
+                n, blen = len(bkt.slots), bkt.pad_len
+                starts = np.asarray(bkt.starts, np.int32)
+                lens = np.asarray(bkt.lens, np.int32)
+                toks = np.zeros((n, blen), np.int32)
+                for r, slot in enumerate(bkt.slots):
                     req = self.slots[slot]
-                    if req._replay_tok is not None:
-                        # swap-corruption re-prefill just replayed already-
-                        # generated tokens: the "first token" of this prefill
-                        # was sampled long ago — restore the decode feed
-                        # instead of appending a duplicate
-                        self.last_tok[slot] = req._replay_tok
-                        req._replay_tok = None
-                    else:
-                        first = int(firsts[r])
-                        req.output.append(first)
-                        req.first_token_t = now
-                        self.last_tok[slot] = first
-                        if self._obs:
-                            tl = self.trace.timeline(req.uid)
-                            tl.add(now, "first_token", slot=slot)
-                            tl.first_token_t = now
-                            tl.last_emit_t = now
-                            self._h_ttft.observe(now - req.arrival_t)
-                    if self.cache is not None:
-                        self._cache_insert_slot(slot)
-            self.stats.prefill_batches += 1
+                    toks[r, : lens[r]] = \
+                        req.prompt[starts[r]: starts[r] + lens[r]]
+                table = jnp.asarray(self.pager.table()[bkt.slots])
+                args = (jnp.asarray(toks), jnp.asarray(lens - 1),
+                        jnp.asarray(starts), jnp.asarray(lens), table)
+                if self.has_fixed:
+                    extra = (jnp.asarray(bkt.slots, jnp.int32),)
+                elif self.has_enc:
+                    extra = (jnp.asarray(self.pager.table("enc")[bkt.slots]),
+                             jnp.asarray(self.enc_len[list(bkt.slots)]))
+                else:
+                    extra = ()
+            with phase("prefill_dispatch"):
+                if self.has_fixed:
+                    logits, self.pools, self.fixed = self._prefill_chunk(
+                        self.params, *args, self.pools, self.fixed, *extra)
+                else:
+                    logits, self.pools = self._prefill_chunk(
+                        self.params, *args, self.pools, *extra)
+                # drop the chunk's uploads once the launch holds them, so
+                # they are not kept alive while the next bucket allocates
+                del args, extra
+                finals = [self.slots[s] if f else None
+                          for s, f in zip(bkt.slots, bkt.final)]
+                if any(bkt.final):
+                    self.key, sk = jax.random.split(self.key)
+                    temps = jnp.asarray(
+                        [r.temperature if r else 0.0 for r in finals],
+                        jnp.float32)
+                    firsts = self._sample_reqs(logits, sk, temps, finals)
+            if any(bkt.final):
+                with phase("first_token_wait"):
+                    firsts = np.asarray(firsts)
+                    now = self._clock()
+            with phase("emit"):
+                if self._obs:
+                    self.trace.note_prefill(blen, bkt.starts, bkt.lens,
+                                            bkt.final)
+                for r, slot in enumerate(bkt.slots):
+                    self.pos[slot] += int(lens[r])
+                    self.stats.prefilled_tokens += int(lens[r])
+                    worked += 1
+                    if self._obs:
+                        self.trace.note_chunk(slot, self.slots[slot].uid,
+                                              int(lens[r]))
+                    if bkt.final[r]:
+                        req = self.slots[slot]
+                        if req._replay_tok is not None:
+                            # swap-corruption re-prefill just replayed
+                            # already-generated tokens: the "first token" of
+                            # this prefill was sampled long ago — restore
+                            # the decode feed instead of appending a
+                            # duplicate
+                            self.last_tok[slot] = req._replay_tok
+                            req._replay_tok = None
+                        else:
+                            first = int(firsts[r])
+                            req.output.append(first)
+                            req.first_token_t = now
+                            self.last_tok[slot] = first
+                            if self._obs:
+                                tl = self.trace.timeline(req.uid)
+                                tl.add(now, "first_token", slot=slot)
+                                tl.first_token_t = now
+                                tl.last_emit_t = now
+                                self._h_ttft.observe(now - req.arrival_t)
+                        if self.cache is not None:
+                            self._cache_insert_slot(slot)
+                self.stats.prefill_batches += 1
         return worked
 
     def _sync_cache_stats(self) -> None:
@@ -1100,46 +1107,49 @@ class ServingEngine:
         prefill target.  Returns the number of rows worked (decode slots +
         chunk rows)."""
         self._step_idx += 1
-        self._retry_pending = False
-        if self._obs:
-            self.trace.begin_step(self._step_idx)
-            pc0 = dict(self.pager.counts)
-        pre_injected = 0
-        if self.faults is not None:
-            self.faults.begin_step(self._step_idx)
-            pre_injected = self.faults.total_injected
-        self._expire_deadlines()
-        worked = self._step_inner()
-        self._sync_cache_stats()
-        if self.faults is not None:
-            self.stats.faults_injected = self.faults.total_injected
-            # any fire this step (e.g. a page_alloc outage rejecting an
-            # otherwise-fine admission) or an active pressure window means a
-            # zero-work step is fault-induced back-off, not a livelock — the
-            # drain guard must keep stepping instead of raising a stall
-            if (self.faults.total_injected > pre_injected
-                    or self.faults.pressure_active()):
-                self._retry_pending = True
-        self._drain_swap_buffers()
-        if self._obs:
-            pc1 = self.pager.counts
-            used = (self.pager.num_pages - 1) - self.pager.free_pages
-            self.metrics.gauge("pool_used_pages").set(used)
-            self.metrics.gauge("active_slots").set(
-                sum(s is not None for s in self.slots))
-            self.trace.end_step(
-                self._last_dec,
-                pages_used=used, pages_free=self.pager.free_pages,
-                pages_grown=pc1["grown"] - pc0["grown"],
-                pages_cow=pc1["cow"] - pc0["cow"],
-                pages_evicted=pc1["evicted"] - pc0["evicted"])
+        self.trace.begin_step(self._step_idx)
+        phase = self.trace.phase
+        with phase("step"):
+            self._retry_pending = False
+            pre_injected = 0
+            if self.faults is not None:
+                self.faults.begin_step(self._step_idx)
+                pre_injected = self.faults.total_injected
+            with phase("expire"):
+                self._expire_deadlines()
+            worked = self._step_inner()
+            self._sync_cache_stats()
+            if self.faults is not None:
+                self.stats.faults_injected = self.faults.total_injected
+                # any fire this step (e.g. a page_alloc outage rejecting an
+                # otherwise-fine admission) or an active pressure window
+                # means a zero-work step is fault-induced back-off, not a
+                # livelock — the drain guard must keep stepping instead of
+                # raising a stall
+                if (self.faults.total_injected > pre_injected
+                        or self.faults.pressure_active()):
+                    self._retry_pending = True
+            with phase("drain_swap"):
+                self._drain_swap_buffers()
+            if self._obs:
+                with phase("journal"):
+                    used = (self.pager.num_pages - 1) - self.pager.free_pages
+                    self.metrics.gauge("pool_used_pages").set(used)
+                    self.metrics.gauge("active_slots").set(
+                        sum(s is not None for s in self.slots))
+                    self.trace.end_step(self._last_dec, pages_used=used,
+                                        pages_free=self.pager.free_pages)
         return worked
 
     def _step_inner(self) -> int:
         self._last_dec = []
-        self._admit()
-        stalled = self._ensure_pages()
-        chunked = self._prefill_chunks()
+        phase = self.trace.phase
+        with phase("admit"):
+            self._admit()
+        with phase("grow"):
+            stalled = self._ensure_pages()
+        with phase("prefill"):
+            chunked = self._prefill_chunks()
         # decode set AFTER chunking: a slot whose final chunk just sampled
         # its first token decodes this same step (parity with the old
         # admit-then-decode flow).  Slots whose lazy growth hit an injected
@@ -1152,77 +1162,98 @@ class ServingEngine:
         # pager tripwires: no active slot may point at the trash page, every
         # refcount must match the tables + swap holds, and the page under
         # each write cursor must be private (shared pages are read-only)
-        try:
-            KV.assert_live_tables(
-                self.pager.table(), self.pos, self.PS,
-                [s is not None and i not in stalled
-                 for i, s in enumerate(self.slots)],
-                refs=self.pager.refs(), held=self.pager.held(),
-                cached=self.pager.cached_mask(),
-                aux_tables=tuple(self.pager.table(g)
-                                 for g in self.pager.groups if g != "kv"))
-        except KV.PagerInvariantError as e:
-            if self.strict or e.slot is None:
-                raise
-            # quarantine: fail the offending request, free what it held,
-            # keep serving everyone else.  Skip this launch (tables may be
-            # mid-repair); the next step re-checks from scratch.
-            self._evict_slot(int(e.slot), "failed",
-                             f"pager invariant violated: {e}")
-            self._retry_pending = True
-            return chunked
+        with phase("check_tables"):
+            try:
+                KV.assert_live_tables(
+                    self.pager.table(), self.pos, self.PS,
+                    [s is not None and i not in stalled
+                     for i, s in enumerate(self.slots)],
+                    refs=self.pager.refs(), held=self.pager.held(),
+                    cached=self.pager.cached_mask(),
+                    aux_tables=tuple(self.pager.table(g)
+                                     for g in self.pager.groups
+                                     if g != "kv"))
+            except KV.PagerInvariantError as e:
+                if self.strict or e.slot is None:
+                    raise
+                # quarantine: fail the offending request, free what it
+                # held, keep serving everyone else.  Skip this launch
+                # (tables may be mid-repair); the next step re-checks from
+                # scratch.
+                self._evict_slot(int(e.slot), "failed",
+                                 f"pager invariant violated: {e}")
+                self._retry_pending = True
+                return chunked
+        with phase("decode"):
+            return self._decode_slots(dec) + chunked
+
+    def _decode_slots(self, dec: List[int]) -> int:
+        """Decode one token for every slot in ``dec``; returns ``len(dec)``
+        (0 when an injected launch fault skips the step)."""
+        phase = self.trace.phase
         if self.faults is not None and self.faults.fires("decode_launch"):
             # the launch died before dispatch — no KV write, no sample, no
             # cursor moved — so retrying next step is always sound; the
             # oldest decode slot carries the bounded charge
             self._charge_retry(min(dec, key=lambda j: self.slots[j].submit_seq),
                                "decode launch faulted")
-            return chunked
-        # mask mid-prefill rows out of the decode launch exactly like empty
-        # slots: trash-page table rows absorb the dummy KV write and the row's
-        # logits are discarded — so their real pages never see a stray write
-        dset = set(dec)
-        tbl_np = self.pager.table().copy()
-        pos_np = self.pos.copy()
-        tok_np = self.last_tok.copy()
-        for i in range(self.B):
-            if i not in dset:
-                tbl_np[i] = KV.TRASH_PAGE
-                pos_np[i] = 0
-                tok_np[i] = 0
-        tok = jnp.asarray(tok_np[:, None])
-        pos = jnp.asarray(pos_np)
-        table = jnp.asarray(tbl_np)
-        if self.has_fixed:
-            # trash-masking covers the KV write but not the recurrence: an
-            # explicit active mask freezes non-decoding rows' fixed state
-            act = np.zeros(self.B, bool)
-            act[list(dset)] = True
-            logits, self.pools, self.fixed = self._decode(
-                self.params, self.pools, self.fixed, tok, pos, table,
-                jnp.asarray(act))
-        elif self.has_enc:
-            # non-decoding rows read the trash page's zero rows with a
-            # zero valid length (clamped to one masked row inside the
-            # model) — their logits are discarded like empty slots'
-            etbl = self.pager.table("enc").copy()
-            elen = self.enc_len.copy()
-            for i in range(self.B):
-                if i not in dset:
-                    etbl[i] = KV.TRASH_PAGE
-                    elen[i] = 0
-            logits, self.pools = self._decode(
-                self.params, self.pools, tok, pos, table,
-                jnp.asarray(etbl), jnp.asarray(elen))
-        else:
-            logits, self.pools = self._decode(
-                self.params, self.pools, tok, pos, table)
-        self.key, sk = jax.random.split(self.key)
-        rows = [self.slots[i] if i in dset else None for i in range(self.B)]
-        temps = jnp.asarray([
-            r.temperature if r else 0.0 for r in rows
-        ], jnp.float32)
-        nxt = np.asarray(self._sample_reqs(logits, sk, temps, rows))
+            return 0
+        with phase("decode_inputs"):
+            # mask mid-prefill rows out of the decode launch exactly like
+            # empty slots: trash-page table rows absorb the dummy KV write
+            # and the row's logits are discarded — so their real pages
+            # never see a stray write
+            dset = set(dec)
+            idle = [i for i in range(self.B) if i not in dset]
+            tbl_np = self.pager.table().copy()
+            pos_np = self.pos.copy()
+            tok_np = self.last_tok.copy()
+            tbl_np[idle] = KV.TRASH_PAGE
+            pos_np[idle] = 0
+            tok_np[idle] = 0
+            args = (jnp.asarray(tok_np[:, None]), jnp.asarray(pos_np),
+                    jnp.asarray(tbl_np))
+            if self.has_fixed:
+                # trash-masking covers the KV write but not the recurrence:
+                # an explicit active mask freezes non-decoding rows' fixed
+                # state
+                act = np.zeros(self.B, bool)
+                act[dec] = True
+                extra = (jnp.asarray(act),)
+            elif self.has_enc:
+                # non-decoding rows read the trash page's zero rows with a
+                # zero valid length (clamped to one masked row inside the
+                # model) — their logits are discarded like empty slots'
+                etbl = self.pager.table("enc").copy()
+                elen = self.enc_len.copy()
+                etbl[idle] = KV.TRASH_PAGE
+                elen[idle] = 0
+                extra = (jnp.asarray(etbl), jnp.asarray(elen))
+            else:
+                extra = ()
+        with phase("decode_dispatch"):
+            if self.has_fixed:
+                logits, self.pools, self.fixed = self._decode(
+                    self.params, self.pools, self.fixed, *args, *extra)
+            else:
+                logits, self.pools = self._decode(
+                    self.params, self.pools, *args, *extra)
+            self.key, sk = jax.random.split(self.key)
+            rows = [self.slots[i] if i in dset else None
+                    for i in range(self.B)]
+            temps = jnp.asarray([
+                r.temperature if r else 0.0 for r in rows
+            ], jnp.float32)
+            nxt = self._sample_reqs(logits, sk, temps, rows)
+        with phase("token_wait"):
+            nxt = np.asarray(nxt)
+        with phase("emit"):
+            self._emit_tokens(dec, nxt)
+        return len(dec)
+
+    def _emit_tokens(self, dec: List[int], nxt: np.ndarray) -> None:
+        """Append each decoded slot's sampled token, and finish and free the
+        slots that reached their end."""
         self.stats.steps += 1
         self.stats.max_active = max(self.stats.max_active, len(dec))
         self.stats.active_slot_steps += len(dec)
@@ -1230,6 +1261,8 @@ class ServingEngine:
         # one clock reading covers every token this step emitted (they left
         # the same launch) — the ITL anchor and done_t share it
         now = self._clock() if self._obs else None
+        if self._obs:
+            self.trace.note_decode(int(self.pos[i]) + 1 for i in dec)
         for i in dec:
             req = self.slots[i]
             t = int(nxt[i])
@@ -1265,7 +1298,6 @@ class ServingEngine:
                 self.pref_target[i] = 0
                 self.enc_len[i] = 0
                 self.pager.free_slot(i)
-        return len(dec) + chunked
 
     def _drain_swap_buffers(self) -> None:
         """Finish pending swap-out transfers: the async device→host copy

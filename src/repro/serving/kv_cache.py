@@ -110,10 +110,10 @@ class PagePool:
         self._cached: set = set()                   # prefix-cache resident
         self._evictor = None                        # PrefixCache (or None)
         self.faults = None                          # FaultPlan (or None)
-        # cumulative page-event counters for observability: the engine's
-        # step journal diffs these across a step to attribute page churn
-        # (grown/COW'd/attached/freed/evicted) to the step that caused it.
-        # Pure host-side ints — recording never touches device state.
+        # cumulative page-event counters for observability
+        # (grown/COW'd/attached/freed/evicted), reported by
+        # ``metrics_snapshot()``.  Pure host-side ints — recording never
+        # touches device state.
         self.counts: Dict[str, int] = {
             "grown": 0, "cow": 0, "attached": 0, "freed": 0, "evicted": 0}
 

@@ -1,7 +1,7 @@
 """Per-request lifecycle timelines + per-step engine journal + Chrome export.
 
-Two recording surfaces, both bounded-memory and host-only (no jax, no RNG —
-recording must never perturb the compute graph or sampling stream):
+Two recording surfaces, both bounded-memory and host-only (no arrays, no
+RNG — recording must never perturb the compute graph or sampling stream):
 
 - :class:`RequestTimeline` — timestamped lifecycle events of one request
   (``submit → admit → prefill_chunk* → first_token → preempt/swap_in →
@@ -11,10 +11,19 @@ recording must never perturb the compute graph or sampling stream):
   truth, not a benchmark-side stopwatch.  Finished timelines move to a
   bounded deque (oldest evicted), live ones are keyed by uid.
 
-- :class:`StepRecord` — one journal row per engine step: decode batch size,
-  chunk tokens scheduled, pages grown/COW/evicted this step, fault probes
-  fired, pool occupancy.  The journal is a ring buffer (``deque(maxlen)``),
-  so a million-step serve holds the last N steps only.
+- :class:`StepRecord` — one journal row per engine step: decode slots and
+  their context lengths, chunk tokens scheduled and the prefill buckets
+  launched, fault probes fired, pool occupancy.  The journal is a ring
+  buffer (``deque(maxlen)``), so a million-step serve holds the last N
+  steps only.
+
+Besides, :meth:`TraceRecorder.phase` opens the engine's phase spans
+(``engine.step``, ``engine.admit``, ``engine.decode``, ...) on the JAX
+profiler's clock, with the step index as their ``step`` argument.  They
+are emitted whether recording is on or off and cost about a microsecond
+each when no profiler is attached; any profiler session
+(``jax.profiler.trace``, ``start_server``) shows them beside the device's
+operations.
 
 :func:`to_chrome_trace` renders both into Chrome ``trace_event`` JSON
 (https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU)
@@ -31,6 +40,8 @@ import dataclasses
 import json
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import jax
 
 __all__ = ["RequestTimeline", "StepRecord", "TraceRecorder",
            "to_chrome_trace", "write_chrome_trace"]
@@ -68,9 +79,11 @@ class StepRecord:
     faults: Tuple[str, ...]                # fault sites fired this step
     pages_used: int
     pages_free: int
-    pages_grown: int                       # lazy growth this step
-    pages_cow: int                         # COW copies this step
-    pages_evicted: int                     # cache evictions this step
+    # context length of each decoding row, the new token included
+    decode_ctx: Tuple[int, ...] = ()
+    # one (pad_len, starts, lens, final) per prefill bucket launched
+    prefill: Tuple[Tuple[int, Tuple[int, ...], Tuple[int, ...],
+                         Tuple[bool, ...]], ...] = ()
 
     @property
     def chunk_tokens(self) -> int:
@@ -89,6 +102,7 @@ class TraceRecorder:
         self.journal: deque = deque(maxlen=journal_len)
         self.live: Dict[int, RequestTimeline] = {}
         self.finished: deque = deque(maxlen=keep_finished)
+        self.step = 0          # the phase spans' ``step``, kept on or off
         # per-step scratch, flushed by end_step()
         self._step: Optional[int] = None
         self._t0 = 0.0
@@ -96,6 +110,8 @@ class TraceRecorder:
         self._preempts: List[Tuple[int, int]] = []
         self._resumes: List[Tuple[int, int]] = []
         self._faults: List[str] = []
+        self._decode_ctx: Tuple[int, ...] = ()
+        self._prefill: List[tuple] = []
 
     # ------------------------------------------------------- timelines ---
     def timeline(self, uid: int) -> RequestTimeline:
@@ -122,8 +138,15 @@ class TraceRecorder:
         return list(self.finished) + [
             self.live[u] for u in sorted(self.live)]
 
+    # ----------------------------------------------------------- spans ---
+    def phase(self, name: str) -> jax.profiler.TraceAnnotation:
+        """Context manager spanning one engine phase, ``engine.<name>``, on
+        the profiler's clock, tagged with the current step index."""
+        return jax.profiler.TraceAnnotation(f"engine.{name}", step=self.step)
+
     # --------------------------------------------------------- journal ---
     def begin_step(self, step: int) -> None:
+        self.step = step
         if not self.enabled:
             return
         self._step = step
@@ -132,6 +155,8 @@ class TraceRecorder:
         self._preempts = []
         self._resumes = []
         self._faults = []
+        self._decode_ctx = ()
+        self._prefill = []
 
     def note_chunk(self, slot: int, uid: int, tokens: int) -> None:
         if self.enabled and self._step is not None:
@@ -149,9 +174,18 @@ class TraceRecorder:
         if self.enabled and self._step is not None:
             self._faults.append(site)
 
-    def end_step(self, decode_slots, *, pages_used: int, pages_free: int,
-                 pages_grown: int, pages_cow: int,
-                 pages_evicted: int) -> None:
+    def note_prefill(self, pad_len: int, starts, lens, final) -> None:
+        if self.enabled and self._step is not None:
+            self._prefill.append((int(pad_len), tuple(map(int, starts)),
+                                  tuple(map(int, lens)),
+                                  tuple(map(bool, final))))
+
+    def note_decode(self, ctx) -> None:
+        if self.enabled and self._step is not None:
+            self._decode_ctx = tuple(map(int, ctx))
+
+    def end_step(self, decode_slots, *, pages_used: int,
+                 pages_free: int) -> None:
         if not self.enabled or self._step is None:
             return
         self.journal.append(StepRecord(
@@ -159,8 +193,8 @@ class TraceRecorder:
             decode_slots=tuple(decode_slots), chunks=tuple(self._chunks),
             preempts=tuple(self._preempts), resumes=tuple(self._resumes),
             faults=tuple(self._faults), pages_used=pages_used,
-            pages_free=pages_free, pages_grown=pages_grown,
-            pages_cow=pages_cow, pages_evicted=pages_evicted))
+            pages_free=pages_free, decode_ctx=self._decode_ctx,
+            prefill=tuple(self._prefill)))
         self._step = None
 
 

@@ -120,8 +120,7 @@ def test_trace_ring_buffers_bound_memory():
     for step in range(10):
         rec.begin_step(step)
         t[0] += 0.001
-        rec.end_step([0], pages_used=1, pages_free=7, pages_grown=0,
-                     pages_cow=0, pages_evicted=0)
+        rec.end_step([0], pages_used=1, pages_free=7)
     assert len(rec.journal) == 4
     assert [s.step for s in rec.journal] == [6, 7, 8, 9]
     for uid in range(5):
@@ -138,8 +137,7 @@ def test_trace_disabled_records_nothing_but_still_tells_time():
     assert rec.event(1, "submit") == 5.0   # callers still get a timestamp
     rec.begin_step(0)
     rec.note_chunk(0, 1, 8)
-    rec.end_step([0], pages_used=0, pages_free=0, pages_grown=0,
-                 pages_cow=0, pages_evicted=0)
+    rec.end_step([0], pages_used=0, pages_free=0)
     assert not rec.journal and not rec.live and not rec.finished
 
 
@@ -156,8 +154,7 @@ def _golden_recorder():
     tl.add(0.0005, "admit", slot=0, cached_tokens=0)
     rec.note_fault("page_alloc")
     t[0] = 0.001
-    rec.end_step([], pages_used=2, pages_free=6, pages_grown=2,
-                 pages_cow=0, pages_evicted=0)
+    rec.end_step([], pages_used=2, pages_free=6)
     tl.add(0.001, "first_token", slot=0)
     rec.begin_step(1)
     tl.add(0.0015, "preempt", slot=0, bytes=1024)
@@ -165,8 +162,7 @@ def _golden_recorder():
     tl.add(0.0018, "swap_in", slot=1)
     rec.note_resume(7, 1)
     t[0] = 0.002
-    rec.end_step([1], pages_used=3, pages_free=5, pages_grown=1,
-                 pages_cow=0, pages_evicted=0)
+    rec.end_step([1], pages_used=3, pages_free=5)
     tl.add(0.002, "finish", reason="completed")
     tl.finish_t = 0.002
     rec.finish(7)
