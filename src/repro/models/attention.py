@@ -347,6 +347,9 @@ def init_gqa_cache(cfg: ModelConfig, batch: int, smax: int) -> Dict[str, jax.Arr
 # multiples, which the TPU lowering requires (a [.., page_size, H, D] pool
 # would need a block of 1 on its head axis).  Rows enter and leave in
 # logical order as [..., H, *D]; only the helpers below know the layout.
+# Writes go through scatter_rows, which addresses each (page, head, offset)
+# row of the flat [num_pages·H·page_size, *D] view, so a step updates the
+# pools in place in this layout, the one the kernels read.
 def init_page_pool(dims: Dict[str, int], num_pages: int, heads: int,
                    page_size: int, dtype,
                    kv_quant: bool) -> Dict[str, jax.Array]:
@@ -364,8 +367,21 @@ def init_page_pool(dims: Dict[str, int], num_pages: int, heads: int,
 def scatter_rows(leaf: jax.Array, page: jax.Array, off: jax.Array,
                  rows: jax.Array) -> jax.Array:
     """Write rows ``[*I, H, *D]`` into ``leaf`` at pool pages ``page[*I]``,
-    in-page offsets ``off[*I]``."""
-    return leaf.at[page, :, off].set(rows.astype(leaf.dtype))
+    in-page offsets ``off[*I]``.
+
+    Each head's row is one whole minor row of a flat ``[NP·H·PS, *D]`` view
+    of the leaf, at ``(page·H + h)·PS + off``, so the scatter updates the
+    pool in place in its own layout.  A scatter whose window spans the head
+    axis but skips the page-offset axis (``leaf.at[page, :, off]``) makes
+    XLA pick a transposed layout for the leaf instead: on the TPU that cost
+    three whole-pool copies per leaf per step."""
+    n_pages, heads, page_size, *d = leaf.shape
+    idx = ((page[..., None] * heads + jnp.arange(heads, dtype=page.dtype))
+           * page_size + off[..., None])                       # [*I, H]
+    flat = leaf.reshape(n_pages * heads * page_size, *d)
+    flat = flat.at[idx.reshape(-1)].set(
+        rows.reshape(-1, *d).astype(leaf.dtype))
+    return flat.reshape(leaf.shape)
 
 
 def gather_pages(pool: jax.Array, table_rows: jax.Array) -> jax.Array:

@@ -6,9 +6,13 @@ for one chip of a *described* ``v5e:2x2`` topology — no chip needed, about a
 second per kernel.  Widths: StarCoder2-15B (d_model 6144, d_ff 24576, 48
 query / 4 KV heads of 128, group size 128) for the dense GEMMs and GQA
 attention; DeepSeek-V2 (128 heads, latent rank 512, rope dim 64, 160
-experts of 1536 over d_model 5120) for MLA and the grouped GEMM.
+experts of 1536 over d_model 5120) for MLA and the grouped GEMM.  The last
+test reads the compiled text of a paged KV write feeding the GQA kernels:
+the pools must be updated in place, with no whole-pool relayout copy.
 """
 import importlib
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.quantize import QuantizedTensor
+from repro.models.attention import _scatter_chunk, _write_token
 
 W4 = importlib.import_module("repro.kernels.w4a16_matmul")
 W4G = importlib.import_module("repro.kernels.w4a16_grouped")
@@ -144,3 +149,49 @@ def test_flash_attention(sds):
     _compile(lambda q, k, v: FA.flash_attention(q, k, v, causal=True),
              sds((1, t, HQ, DH), BF16), sds((1, t, HKV, DH), BF16),
              sds((1, t, HKV, DH), BF16))
+
+
+def _pool_copies(text, pool_elems):
+    """Compiled ``copy`` / ``copy_bitcast_fusion`` ops whose result holds
+    as many elements as a whole pool leaf (in any shape)."""
+    found = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* (\S+)\(",
+                         text, re.M):
+        name, dims, op = m.groups()
+        size = math.prod(int(d) for d in dims.split(",") if d)
+        if size == pool_elems and (op == "copy" or
+                                   name.startswith("copy_bitcast_fusion")):
+            found.append(m.group(0).strip())
+    return found
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_kv_pool_write_in_place(sds, step):
+    """The paged KV write updates the pools in their own layout: a decode
+    step and a 512-token prefill chunk (one layer, pools donated) compile
+    with no pool-sized relayout copy around the scatter and the kernel."""
+    k, _ = _pool(sds, HKV, DH, False)
+    if step == "decode":
+        def fn(q, kn, vn, k, v, table, pos):
+            pool = _write_token({"k": k, "v": v}, {"k": kn, "v": vn}, table,
+                                pos, False)
+            return PA.gqa_paged_attention(q, pool["k"], pool["v"], table,
+                                          pos + 1, sm_scale=DH ** -0.5), pool
+        args = (sds((B, HKV, HQ // HKV, DH), BF16), sds((B, HKV, DH), BF16),
+                sds((B, HKV, DH), BF16), k, k, sds((B, PAGES), I32),
+                sds((B,), I32))
+    else:
+        t = 512
+
+        def fn(q, ksf, vsf, k, v, table, start, clen):
+            pool = _scatter_chunk({"k": k, "v": v}, {"k": ksf, "v": vsf},
+                                  table, start, clen, False)
+            return PA.gqa_paged_prefill(q, ksf, vsf, pool["k"], pool["v"],
+                                        table, start, clen,
+                                        sm_scale=DH ** -0.5), pool
+        args = (sds((1, t, HKV, HQ // HKV, DH), BF16),
+                sds((1, t, HKV, DH), BF16), sds((1, t, HKV, DH), BF16), k, k,
+                sds((1, PAGES), I32), sds((1,), I32), sds((1,), I32))
+    text = jax.jit(fn, donate_argnums=(3, 4)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert _pool_copies(text, math.prod(k.shape)) == []
