@@ -14,6 +14,12 @@ Pallas W4A16 and paged-attention kernels — the same phases
   page-gather attention) fed the same tokens, within :data:`LOGIT_TOL`, and
   every logit must be finite.
 
+Both sides of that comparison serve one PTQ'd model, so a fault in PTQ
+itself (a smoothing that changes what the model computes) passes here
+unseen.  The benchmark's ``correct`` (``bench/harness/check.py``) compares
+the served tokens with a plain float32 reference of the unquantized model,
+and sees it.
+
     python3 chip_smoke.py
 
 It runs in one process and catches no exception: a failing phase exits

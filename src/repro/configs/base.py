@@ -65,6 +65,10 @@ class ModelConfig:
     enc_layers: int = 0
     tie_embeddings: bool = False
     attn_bias: bool = False            # starcoder2/whisper use biases
+    # the model's published sliding attention window (tokens), or None for
+    # full causal attention; no kernel masks behind a window, so the
+    # serving engine refuses a max_seq that would reach past it
+    sliding_window: Optional[int] = None
     dtype: str = "bfloat16"
     # long-context support marker (sub-quadratic mixer): set per-arch
     subquadratic: bool = False

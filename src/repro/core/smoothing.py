@@ -10,11 +10,13 @@ is *fused into the provider* of that activation so inference sees zero extra
 ops (paper §2.2, Fig. 5):
 
   kind "norm"            divide the preceding (RMS/Layer)Norm scale (and bias)
-  kind "linear_out"      divide the preceding linear's output columns
-                         (exact when the op between them is per-channel
-                         linear: attention·V, SwiGLU's ⊙up, Mamba2's gate)
-  kind "linear_out_sqrt" divide by sqrt(s) — for RWKV6's channel-mix where
-                         the intermediate is relu(·)² (so col scale c → c²)
+  kind "linear_out"      divide the preceding linear's output columns and
+                         its bias (exact when the op between them is
+                         per-channel linear: attention·V, SwiGLU's ⊙up,
+                         Mamba2's gate)
+  kind "linear_out_sqrt" divide columns and bias by sqrt(s) — for RWKV6's
+                         channel-mix where the intermediate is relu(·)² (so
+                         col scale c → c²)
   kind "linear_out_mla_v" divide only the V-columns of DeepSeek's wkv_b
   kind "none"            no smoothing possible (e.g. GELU MLP down-proj whose
                          producer is non-linear) — the weight is still
@@ -357,6 +359,13 @@ def _align(s: np.ndarray, w: jax.Array) -> jnp.ndarray:
     return jnp.asarray(s.reshape(shape))
 
 
+def _bias_path(params, wp: Path) -> Optional[Path]:
+    """The bias beside the linear weight at ``wp`` (``{"w", "b"}``), if any."""
+    if wp[-1] != "w" or "b" not in tget(params, wp[:-1]):
+        return None
+    return wp[:-1] + ("b",)
+
+
 def apply_group(params, cfg: ModelConfig, group: Group, s: np.ndarray):
     """Scale group weights by s (rows) and fuse 1/s into the provider."""
     if group.provider.kind == "none":
@@ -389,8 +398,18 @@ def apply_group(params, cfg: ModelConfig, group: Group, s: np.ndarray):
         extra = w.ndim - 1 - sd.ndim
         cols = sd.reshape(*sd.shape[:-1], *([1] * extra), 1, sd.shape[-1])
         params = tset(params, pp, (w.astype(jnp.float32) / cols).astype(w.dtype))
+        # the provider's output is x W + b: its bias columns take the same
+        # factor, or the consumer sees x W / s + b for (x W + b) / s
+        bp = _bias_path(params, pp)
+        if bp is not None:
+            b = tget(params, bp)
+            params = tset(params, bp, (b.astype(jnp.float32)
+                                       / cols[..., 0, :]).astype(b.dtype))
     elif pk == "linear_out_mla_v":
         h, nope, v = group.provider.extra
+        if _bias_path(params, pp) is not None:
+            raise ValueError(f"{group.name}: a biased wkv_b would need its "
+                             "V bias columns scaled too")
         w = tget(params, pp)                        # [*lead, r, H*(nope+v)]
         lead = w.shape[:-2]
         r = w.shape[-2]

@@ -28,6 +28,13 @@ from repro.models import ssm as S
 
 Params = Dict[str, Any]
 
+# named scopes of the paged decode and prefill-chunk steps: each op's
+# metadata (``op_name`` in the compiled HLO) says whether it belongs to the
+# layer stack or the head (final norm + lm_head).  Metadata only: the
+# compiled ops are the same with or without them.
+SCOPE_LAYERS = "lm.layers"
+SCOPE_HEAD = "lm.head"
+
 
 # ------------------------------------------------------------ block defs ----
 def _init_mixer(key, cfg: ModelConfig):
@@ -247,10 +254,13 @@ def lm_prefill_chunk(
             backend=backend)
         return x, pool
 
-    x, pools = jax.lax.scan(body, x, (p["layers"], cache["layers"]))
+    with jax.named_scope(SCOPE_LAYERS):
+        x, pools = jax.lax.scan(body, x, (p["layers"], cache["layers"]))
     idx = last_idx if last_idx is not None else jnp.full((b,), t - 1, jnp.int32)
     x_last = x[jnp.arange(b), idx][:, None]
-    return _lm_head(p, x_last, cfg, backend)[:, 0], {"layers": pools}
+    with jax.named_scope(SCOPE_HEAD):
+        logits = _lm_head(p, x_last, cfg, backend)[:, 0]
+    return logits, {"layers": pools}
 
 
 # ------------------------------------------------------------- LM wiring ----
@@ -534,8 +544,10 @@ def lm_decode_paged(
             lp, x, pos, position, st, table_rows, cfg, backend=backend)
         return x, st
 
-    x, nst = jax.lax.scan(step, x, (p["layers"], cache["layers"]))
-    logits = _lm_head(p, x, cfg, backend)[:, 0]
+    with jax.named_scope(SCOPE_LAYERS):
+        x, nst = jax.lax.scan(step, x, (p["layers"], cache["layers"]))
+    with jax.named_scope(SCOPE_HEAD):
+        logits = _lm_head(p, x, cfg, backend)[:, 0]
     return logits, {"layers": nst}
 
 

@@ -258,6 +258,12 @@ class ServingEngine:
         self.PS = page_size
         self.P = -(-max_seq // page_size)          # pages per slot
         self.S = self.P * page_size                # max_seq rounded to pages
+        if cfg.sliding_window is not None and self.S > cfg.sliding_window:
+            raise ValueError(
+                f"{cfg.name}: max_seq={max_seq} (pages hold {self.S}) "
+                f"reaches past the model's sliding window of "
+                f"{cfg.sliding_window} tokens, and no attention path masks "
+                "behind a window")
         self.eos = eos_id
         self.backend = backend
         self.key = jax.random.PRNGKey(seed)

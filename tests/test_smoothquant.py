@@ -53,9 +53,34 @@ def test_stats_cover_all_groups(setup):
         assert np.all(st >= 0) and np.isfinite(st).all()
 
 
+def _draw_biases(params, key):
+    """Every linear bias (``{"w", "b"}``) and every LayerNorm bias
+    (``{"scale", "bias"}``) drawn from a seeded normal: ``init_linear`` and
+    ``init_norm`` make them zero, and a zero bias hides a smoothing that
+    forgets to scale it."""
+    drawn = 0
+
+    def walk(node):
+        nonlocal drawn
+        if not isinstance(node, dict):
+            return node
+        out = {k: walk(node[k]) for k in sorted(node)}
+        for leaf, sib in (("b", "w"), ("bias", "scale")):
+            if leaf in out and sib in out:
+                b = out[leaf]
+                drawn += 1
+                out[leaf] = (0.5 * jax.random.normal(
+                    jax.random.fold_in(key, drawn), b.shape)).astype(b.dtype)
+        return out
+
+    return walk(params)
+
+
 def test_smoothing_is_mathematically_equivalent(setup):
-    """Paper eq. 5: smoothed (unquantized) model output == original."""
+    """Paper eq. 5: smoothed (unquantized) model output == original, with
+    every linear and LayerNorm bias drawn (nonzero)."""
     cfg, params, batches, col = setup
+    params = _draw_biases(params, jax.random.PRNGKey(1))
     smoothed, s_map = SM.smooth_model(params, cfg, col, alpha=0.5)
     assert s_map, "no groups smoothed"
     batch = batches[0]
