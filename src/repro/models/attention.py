@@ -175,25 +175,27 @@ def _chunk_positions(start_len: jax.Array, t: int) -> jax.Array:
 
 def _scatter_chunk(pool: Dict[str, jax.Array], updates: Dict[str, jax.Array],
                    table_rows: jax.Array, start_len: jax.Array,
-                   chunk_len: jax.Array, kv_quant: bool):
+                   chunk_len: jax.Array, kv_quant: bool, page_base=0):
     """Scatter a ``[B, T, H, D]`` chunk of raw KV rows into the paged pools at
     logical positions ``start_len[b] + t`` (quantizing per row under
     ``kv_quant``).  Padded rows (``t >= chunk_len[b]``) land on the trash
-    page, exactly like ``prefix_write_plan`` routes invalid rows."""
+    page ``page_base`` (the first page of this layer's range in a carried
+    pool view), exactly like ``prefix_write_plan`` routes invalid rows."""
     b, t = next(iter(updates.values())).shape[:2]
     ps = pool[next(iter(updates))].shape[2]
     n_pages = table_rows.shape[1]
     pos = _chunk_positions(start_len, t)
     valid = jnp.arange(t, dtype=jnp.int32)[None, :] < chunk_len[:, None]
     lpage = jnp.minimum(pos // ps, n_pages - 1)
-    pg = jnp.where(valid, table_rows[jnp.arange(b)[:, None], lpage], 0)
+    pg = jnp.where(valid, table_rows[jnp.arange(b)[:, None], lpage],
+                   page_base)
     return _write_rows(pool, updates, pg, pos % ps, kv_quant)
 
 
 def gqa_prefill_chunk(
     p, x, pool: Dict[str, jax.Array], table_rows: jax.Array,
     start_len: jax.Array, chunk_len: jax.Array, cfg: ModelConfig, *,
-    backend: str = "auto"
+    backend: str = "auto", page_base=0
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Chunked prefill straight against the paged pools.
 
@@ -206,8 +208,8 @@ def gqa_prefill_chunk(
     chunked-prefill grid on the kernel impls, or the dense ``gather_pages``
     oracle under ``paged_attn_impl="gather"`` — while the chunk attends its
     own suffix K/V raw (pre-quantization), keeping slab-prefill numerics.
-    Rows with ``t >= chunk_len[b]`` are padding: scattered to trash, masked
-    out as keys.
+    Rows with ``t >= chunk_len[b]`` are padding: scattered to the trash page
+    ``page_base`` (see the page-pool notes below), masked out as keys.
     """
     b, t, _ = x.shape
     h, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.hdim
@@ -215,7 +217,7 @@ def gqa_prefill_chunk(
     positions = _chunk_positions(start_len, t)
     q, k, v = _qkv(p, x, positions, cfg, backend)
     new_pool = _scatter_chunk(pool, {"k": k, "v": v}, table_rows, start_len,
-                              chunk_len, cfg.kv_quant)
+                              chunk_len, cfg.kv_quant, page_base)
     scale = dh ** -0.5
     impl = _resolve_paged_impl(cfg, backend)
 
@@ -350,6 +352,14 @@ def init_gqa_cache(cfg: ModelConfig, batch: int, smax: int) -> Dict[str, jax.Arr
 # Writes go through scatter_rows, which addresses each (page, head, offset)
 # row of the flat [num_pages·H·page_size, *D] view, so a step updates the
 # pools in place in this layout, the one the kernels read.
+# A model stacks one pool per layer, [L, num_pages, ...].  Its paged layer
+# scans (models/lm.py) carry each leaf as one [L·num_pages, ...] view in
+# which layer l owns pages [l·num_pages, (l+1)·num_pages), and hand every
+# function here the page table offset by the page base l·num_pages.  Page
+# numbers are then global to the view: reads, writes and the trash page
+# (page_base, where padded chunk rows land) all address layer l's range,
+# and the whole stack is updated in place.  At L = 1 the base is 0 and the
+# view is the pool itself.
 def init_page_pool(dims: Dict[str, int], num_pages: int, heads: int,
                    page_size: int, dtype,
                    kv_quant: bool) -> Dict[str, jax.Array]:
@@ -593,17 +603,18 @@ def mla_prefill(
 def mla_prefill_chunk(
     p, x, pool: Dict[str, jax.Array], table_rows: jax.Array,
     start_len: jax.Array, chunk_len: jax.Array, cfg: ModelConfig, *,
-    backend: str = "auto"
+    backend: str = "auto", page_base=0
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Chunked MLA prefill against the paged latent pools, absorbed form.
 
     Same chunk contract as :func:`gqa_prefill_chunk` — positions are
     ``start_len[b] + t``, the chunk's raw latents scatter into the slot's
-    pages, padding rows go to trash.  Attention runs absorbed (scores in the
-    latent space, like decode) so the prefix pages stream through the Pallas
-    grid without ever re-expanding ``wkv_b`` over a dense gathered copy; the
-    chunk's own latents are attended raw (pre-quantization).  The absorbed
-    projections ride the grouped W4A16 kernel when ``wkv_b`` is int4.
+    pages, padding rows go to the trash page ``page_base``.  Attention runs
+    absorbed (scores in the latent space, like decode) so the prefix pages
+    stream through the Pallas grid without ever re-expanding ``wkv_b`` over
+    a dense gathered copy; the chunk's own latents are attended raw
+    (pre-quantization).  The absorbed projections ride the grouped W4A16
+    kernel when ``wkv_b`` is int4.
     """
     m = cfg.mla
     b, t, _ = x.shape
@@ -613,7 +624,8 @@ def mla_prefill_chunk(
     ckv_suf, kpe_suf = _mla_latent(p, x, positions, cfg, backend)
     new_pool = _scatter_chunk(pool, {"ckv": ckv_suf[:, :, None],
                                      "kpe": kpe_suf[:, :, None]},
-                              table_rows, start_len, chunk_len, cfg.kv_quant)
+                              table_rows, start_len, chunk_len, cfg.kv_quant,
+                              page_base)
     q_lat = _mla_absorb_q_lat(
         p, q_nope.reshape(b * t, h, m.qk_nope_head_dim), cfg, backend
     ).reshape(b, t, h, -1)

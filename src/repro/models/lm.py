@@ -173,7 +173,8 @@ def _block_decode(p, x, positions, cache, cfg, *, mixer=None, backend="auto"):
 def _block_decode_paged(p, x, rope_pos, write_pos, pool, table_rows, cfg,
                         *, mixer=None, backend="auto"):
     """Attention-mixer block decode against a paged KV pool (see
-    ``models/attention.py`` for the page-table convention).  ``mixer``
+    ``models/attention.py`` for the page-table convention; in a layer scan
+    ``table_rows`` is already offset to this layer's pages).  ``mixer``
     overrides ``cfg.mixer`` (the hybrid stack's weight-shared attention)."""
     mixer = mixer or cfg.mixer
     h = L.apply_norm(p["norm1"], x)
@@ -197,21 +198,22 @@ def _block_decode_paged(p, x, rope_pos, write_pos, pool, table_rows, cfg,
 
 
 def _block_prefill_chunk(p, x, start_len, chunk_len, pool, table_rows, cfg,
-                         *, mixer=None, backend="auto"):
+                         *, mixer=None, backend="auto", page_base=0):
     """Attention-mixer block chunked prefill straight against a paged KV pool
-    (see ``models/attention.py`` for the chunk contract).  ``mixer`` overrides
-    ``cfg.mixer`` (the hybrid stack's weight-shared attention).  Returns
-    (x, updated pool)."""
+    (see ``models/attention.py`` for the chunk contract; in a layer scan
+    ``table_rows`` is already offset by ``page_base``, this layer's trash
+    page).  ``mixer`` overrides ``cfg.mixer`` (the hybrid stack's
+    weight-shared attention).  Returns (x, updated pool)."""
     mixer = mixer or cfg.mixer
     h = L.apply_norm(p["norm1"], x)
     if mixer == "attention":
         y, pool = A.gqa_prefill_chunk(
             p["mixer"], h, pool, table_rows, start_len, chunk_len, cfg,
-            backend=backend)
+            backend=backend, page_base=page_base)
     elif mixer == "mla":
         y, pool = A.mla_prefill_chunk(
             p["mixer"], h, pool, table_rows, start_len, chunk_len, cfg,
-            backend=backend)
+            backend=backend, page_base=page_base)
     else:
         raise ValueError(f"paged prefill needs an attention mixer, got {mixer}")
     x = x + y
@@ -241,26 +243,56 @@ def lm_prefill_chunk(
     token (cached prefix pages and prior chunks alike) through the page
     table.  Row ``b``'s chunk token ``t`` sits at logical position
     ``start_len[b] + t``.  Returns per-row logits gathered at ``last_idx``
-    (only meaningful on a prompt's final chunk) and the updated pools — the
-    pools ride the layer scan as ys, exactly like :func:`lm_decode_paged`.
+    (only meaningful on a prompt's final chunk) and the updated pools.  The
+    pools ride the layer scan's carry as one ``[L·NP, ...]`` view, layer
+    ``l`` at page base ``l·NP`` (:func:`_scan_paged_layers`), exactly like
+    :func:`lm_decode_paged`.
     """
     b, t = tokens.shape[:2]
     x = _embed_in(p, tokens, cfg, embeds)
 
-    def body(x, inp):
-        lp, pool = inp
-        x, pool = _block_prefill_chunk(
-            lp, x, start_len, chunk_len, pool, table_rows, cfg,
-            backend=backend)
-        return x, pool
+    def body(x, lp, pools, base):
+        x, pools = _block_prefill_chunk(
+            lp, x, start_len, chunk_len, pools, table_rows + base, cfg,
+            backend=backend, page_base=base)
+        return x, pools, None
 
     with jax.named_scope(SCOPE_LAYERS):
-        x, pools = jax.lax.scan(body, x, (p["layers"], cache["layers"]))
+        x, pools, _ = _scan_paged_layers(body, x, p["layers"],
+                                         cache["layers"])
     idx = last_idx if last_idx is not None else jnp.full((b,), t - 1, jnp.int32)
     x_last = x[jnp.arange(b), idx][:, None]
     with jax.named_scope(SCOPE_HEAD):
         logits = _lm_head(p, x_last, cfg, backend)[:, 0]
     return logits, {"layers": pools}
+
+
+def _scan_paged_layers(body, x, xs, pools):
+    """Scan ``body`` over the layer axis of ``xs`` with the stacked KV pools
+    ``[L, NP, ...]`` in the carry.
+
+    Each pool leaf rides the scan as one flat ``[L·NP, ...]`` view (merging
+    the two leading axes is a bitcast) in which layer ``l`` owns pages
+    ``[l·NP, (l+1)·NP)``, its trash page first.  ``body(x, xs_l, pools,
+    base)`` returns ``(x, pools, ys_l)`` and addresses layer ``l``'s pages
+    through ``table_rows + base`` with ``base = l·NP``, so its writes update
+    the one carried buffer in place.  (As the scan's xs/ys, the pools would
+    be sliced out per layer, written back into a new stack, and the whole
+    stack copied again after the scan.)  Returns ``(x, pools, ys)`` with the
+    pools back in their ``[L, NP, ...]`` shape."""
+    n_layers, n_pages = jax.tree.leaves(pools)[0].shape[:2]
+    flat = jax.tree.map(
+        lambda a: a.reshape(n_layers * n_pages, *a.shape[2:]), pools)
+
+    def step(carry, inp):
+        x, flat = carry
+        layer, xs_l = inp
+        x, flat, ys = body(x, xs_l, flat, layer * n_pages)
+        return (x, flat), ys
+
+    (x, flat), ys = jax.lax.scan(
+        step, (x, flat), (jnp.arange(n_layers, dtype=jnp.int32), xs))
+    return x, jax.tree.map(lambda f, a: f.reshape(a.shape), flat, pools), ys
 
 
 # ------------------------------------------------------------- LM wiring ----
@@ -531,21 +563,25 @@ def lm_decode_paged(
     *,
     backend: str = "auto",
 ) -> Tuple[jax.Array, Any]:
-    """One decode step against paged KV pools.  Returns (logits, new pools)."""
+    """One decode step against paged KV pools.  Returns (logits, new pools).
+
+    The pools ride the layer scan's carry as one ``[L·NP, ...]`` view; layer
+    ``l`` reads and writes its pages through ``table_rows + l·NP``
+    (:func:`_scan_paged_layers`), so the step updates them in place."""
     b = token.shape[0]
     pos = position[:, None]
     if cfg.rope == "mrope":
         pos = jnp.broadcast_to(position[None, :, None], (3, b, 1))
     x = L.apply_embedding(p["embed"], token)
 
-    def step(x, inp):
-        lp, st = inp
-        x, st = _block_decode_paged(
-            lp, x, pos, position, st, table_rows, cfg, backend=backend)
-        return x, st
+    def step(x, lp, pools, base):
+        x, pools = _block_decode_paged(
+            lp, x, pos, position, pools, table_rows + base, cfg,
+            backend=backend)
+        return x, pools, None
 
     with jax.named_scope(SCOPE_LAYERS):
-        x, nst = jax.lax.scan(step, x, (p["layers"], cache["layers"]))
+        x, nst, _ = _scan_paged_layers(step, x, p["layers"], cache["layers"])
     with jax.named_scope(SCOPE_HEAD):
         logits = _lm_head(p, x, cfg, backend)[:, 0]
     return logits, {"layers": nst}
@@ -597,16 +633,16 @@ def hybrid_decode_paged(
         x, st = _block_decode(lp, x, pos, st, cfg, mixer="mamba2", backend=backend)
         return x, st
 
-    def group_step(x, inp):
-        gp, gst, pool = inp
+    def group_step(x, inp, pools, base):
+        gp, gst = inp
         x, new_gst = jax.lax.scan(mamba_step, x, (gp, gst))
-        x, pool = _block_decode_paged(
-            shared, x, pos, position, pool, table_rows, scfg,
+        x, pools = _block_decode_paged(
+            shared, x, pos, position, pools, table_rows + base, scfg,
             mixer="attention", backend=backend)
-        return x, (new_gst, pool)
+        return x, pools, new_gst
 
-    x, (ngst, npools) = jax.lax.scan(
-        group_step, x, (p["groups"], grouped, cache["layers"]))
+    x, npools, ngst = _scan_paged_layers(
+        group_step, x, (p["groups"], grouped), cache["layers"])
     ntail = tail_st
     if tail:
         x, ntail = jax.lax.scan(mamba_step, x, (p["tail"], tail_st))
@@ -654,16 +690,16 @@ def hybrid_prefill_chunk(
             lp["mixer"], h, st, chunk_len, cfg, backend=backend)
         return x + y, st
 
-    def group_body(x, inp):
-        gp, gst, pool = inp
+    def group_body(x, inp, pools, base):
+        gp, gst = inp
         x, new_gst = jax.lax.scan(mamba_body, x, (gp, gst))
-        x, pool = _block_prefill_chunk(
-            shared, x, start_len, chunk_len, pool, table_rows, scfg,
-            mixer="attention", backend=backend)
-        return x, (new_gst, pool)
+        x, pools = _block_prefill_chunk(
+            shared, x, start_len, chunk_len, pools, table_rows + base, scfg,
+            mixer="attention", backend=backend, page_base=base)
+        return x, pools, new_gst
 
-    x, (ngst, npools) = jax.lax.scan(
-        group_body, x, (p["groups"], grouped, cache["layers"]))
+    x, npools, ngst = _scan_paged_layers(
+        group_body, x, (p["groups"], grouped), cache["layers"])
     ntail = tail_st
     if tail:
         x, ntail = jax.lax.scan(mamba_body, x, (p["tail"], tail_st))

@@ -7,8 +7,10 @@ second per kernel.  Widths: StarCoder2-15B (d_model 6144, d_ff 24576, 48
 query / 4 KV heads of 128, group size 128) for the dense GEMMs and GQA
 attention; DeepSeek-V2 (128 heads, latent rank 512, rope dim 64, 160
 experts of 1536 over d_model 5120) for MLA and the grouped GEMM.  The last
-test reads the compiled text of a paged KV write feeding the GQA kernels:
-the pools must be updated in place, with no whole-pool relayout copy.
+tests read the compiled text of a paged KV write feeding the GQA kernels,
+alone and inside a 2-layer paged decode step and prefill chunk: the pools
+must be updated in place, with no whole-pool relayout copy and no per-layer
+pool slice or write-back.
 """
 import importlib
 import math
@@ -19,7 +21,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.core.quantize import QuantizedTensor
+from repro.models import lm as LM
 from repro.models.attention import _scatter_chunk, _write_token
 
 W4 = importlib.import_module("repro.kernels.w4a16_matmul")
@@ -195,3 +199,56 @@ def test_kv_pool_write_in_place(sds, step):
     text = jax.jit(fn, donate_argnums=(3, 4)).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert _pool_copies(text, math.prod(k.shape)) == []
+
+
+def _pool_moves(text, sizes):
+    """Compiled copies, dynamic slices and dynamic-update-slices (plain ops,
+    or fusions named after them) with a result holding as many elements as
+    one of ``sizes`` (in any shape)."""
+    found = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%(\S+) = (.*)$", text, re.M):
+        name, rest = m.groups()
+        op = re.search(r" ([\w.-]+)\(%", rest)
+        if op is None:
+            continue
+        kind = (op.group(1) in ("copy", "dynamic-slice", "dynamic-update-slice")
+                or name.startswith("copy_bitcast_fusion")
+                or "dynamic-slice" in name or "dynamic-update-slice" in name)
+        results = re.findall(r"\w+\[([\d,]*)\]", rest[:op.start()])
+        if kind and any(math.prod(int(d) for d in dims.split(",") if d)
+                        in sizes for dims in results):
+            found.append(m.group(0).strip())
+    return found
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_kv_pool_carried_through_layer_scan(sds, step, kv_quant):
+    """A 2-layer paged decode step and 512-token prefill chunk (small widths
+    but heads of 128, pools donated, Pallas kernels) update the stacked pools
+    in place: no copy, dynamic slice or dynamic-update-slice the size of a
+    whole stacked pool leaf or of one layer's share of it.  The f32 scale
+    leaves of int8 pools are left out: their 16-wide minor axis is relaid
+    out around the kernels at any depth, one layer included."""
+    cfg = get_config("codellama-7b", smoke=True).with_(
+        num_layers=2, head_dim=DH, kv_quant=kv_quant, paged_attn_impl="pallas")
+    to_sds = lambda a: sds(a.shape, a.dtype)
+    params = jax.tree.map(to_sds, jax.eval_shape(
+        lambda: LM.init_lm(jax.random.PRNGKey(0), cfg)))
+    cache = jax.tree.map(to_sds, jax.eval_shape(
+        lambda: LM.init_paged_cache(cfg, NP, PS)))
+    sizes = {n for name, a in cache["layers"].items() if not name.endswith("_s")
+             for n in (math.prod(a.shape), math.prod(a.shape[1:]))}
+    if step == "decode":
+        fn = lambda p, c, tok, pos, table: LM.lm_decode_paged(
+            p, tok, c, pos, table, cfg, backend="pallas")
+        args = (params, cache, sds((B, 1), I32), sds((B,), I32),
+                sds((B, PAGES), I32))
+    else:
+        fn = lambda p, c, tok, start, clen, table: LM.lm_prefill_chunk(
+            p, tok, c, start, clen, table, cfg, backend="pallas")
+        args = (params, cache, sds((1, 512), I32), sds((1,), I32),
+                sds((1,), I32), sds((1, PAGES), I32))
+    text = jax.jit(fn, donate_argnums=1).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert _pool_moves(text, sizes) == []
