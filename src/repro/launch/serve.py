@@ -172,8 +172,6 @@ def main(argv=None):
                          "artifact hash covers configs, not weight values)")
     ap.add_argument("--page-size", type=int, default=16,
                     help="KV cache page size (tokens)")
-    ap.add_argument("--prefill-mode", choices=("bucketed", "slotwise"),
-                    default="bucketed")
     ap.add_argument("--max-prefill-tokens", type=int, default=None,
                     help="padded-token budget per engine step (chunked prefill)")
     ap.add_argument("--reservation", choices=("lazy", "worstcase"),
@@ -205,8 +203,7 @@ def main(argv=None):
                     help="seed for the --chaos fault plan")
     ap.add_argument("--no-metrics", action="store_true",
                     help="disable engine observability (timelines, "
-                         "histograms, step journal) — the overhead-"
-                         "benchmark baseline configuration")
+                         "histograms, step journal)")
     ap.add_argument("--trace-out", default=None,
                     help="write the served batch's step journal + request "
                          "timelines as Chrome trace_event JSON (open in "
@@ -258,7 +255,6 @@ def main(argv=None):
                         max_seq=args.max_seq, backend="auto",
                         page_size=args.page_size,
                         num_pages=args.num_pages,
-                        prefill_mode=args.prefill_mode,
                         max_prefill_tokens=args.max_prefill_tokens,
                         reservation=args.reservation,
                         prefix_cache=prefix_cache,
@@ -275,7 +271,7 @@ def main(argv=None):
     accepted, dt = serve_requests(eng, reqs)
 
     # every stat line below renders engine.metrics_snapshot() — the single
-    # structured source for operator reporting (benchmarks read it too)
+    # structured source for operator reporting
     snap = eng.metrics_snapshot()
     st = snap["engine"]
 

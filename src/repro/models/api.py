@@ -131,13 +131,17 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int):
     return LM.init_paged_cache(cfg, num_pages, page_size)
 
 
-def init_fixed_state(cfg: ModelConfig, batch: int):
-    """Fixed-rows state tree ``[M, B, ...]`` (slot axis second) for configs
-    whose :func:`state_leaves` include ``fixed_rows``; the same
-    :func:`gather_pool_rows` / :func:`scatter_pool_rows` helpers move a
-    slot's rows for swap because the slot axis matches the pools' page
-    axis."""
-    return LM.init_fixed_state(cfg, batch)
+def init_paged_state(cfg: ModelConfig, num_pages: int, page_size: int,
+                     batch: int):
+    """The serving engine's device state, the one tree its step programs
+    take, donate and return: :func:`init_paged_cache`'s pools (KV pages
+    under ``"layers"``), plus, for ``fixed_rows`` configs, the per-slot
+    recurrent rows ``[M, batch, ...]`` under ``"fixed"`` (slot axis second,
+    so :func:`gather_pool_rows` moves a slot's rows like pool pages)."""
+    state = init_paged_cache(cfg, num_pages, page_size)
+    if FIXED_ROWS in state_leaves(cfg):
+        state["fixed"] = LM.init_fixed_state(cfg, batch)
+    return state
 
 
 def encode_kv_fn(params, frames, cfg: ModelConfig, *, backend: str = "auto"):
@@ -218,52 +222,47 @@ def copy_pool_page(pools, src: jax.Array, dst: jax.Array):
     return jax.tree.map(lambda leaf: leaf.at[:, dst].set(leaf[:, src]), pools)
 
 
-def prefill_chunk_fn(params, batch, cache, table_rows, start_len, chunk_len,
-                     cfg: ModelConfig, *, backend: str = "auto",
-                     last_idx=None, fixed=None, slots=None,
-                     enc_table=None, enc_len=None):
-    """Chunked prefill straight into the paged pools: one ``[B, T]`` prompt
-    chunk per slot at logical positions ``start_len[b] + t``; KV scatters
-    per chunk, attention reads every earlier token (cached prefix and prior
-    chunks alike) through ``table_rows``.  Returns (per-row last-token
-    logits — meaningful on final chunks — and the updated pools).
-
-    Hybrid configs additionally take/return the fixed-rows state tree
-    (``fixed`` + the bucket's ``slots``) — a 3-tuple result; enc-dec
-    configs take the slot's encoder page table + valid length."""
+def prefill_chunk_fn(params, batch, state, table_rows, start_len, chunk_len,
+                     cfg: ModelConfig, aux=None, *, backend: str = "auto",
+                     last_idx=None):
+    """Chunked prefill straight into the paged state: one ``[B, T]`` prompt
+    chunk per row at logical positions ``start_len[b] + t``; KV scatters
+    per chunk, attention reads every earlier token through ``table_rows``.
+    ``aux`` holds the per-row inputs of the non-KV leaves (None for
+    attention-only state): ``slots`` and ``active`` for fixed rows,
+    ``enc_table`` and ``enc_len`` for encoder pages.  Returns ``(logits,
+    state)``, logits of each row's last token (meaningful on final chunks)."""
     if cfg.encdec:
         return W.whisper_prefill_chunk(
-            params, batch["tokens"], cache, start_len, chunk_len, table_rows,
-            enc_table, enc_len, cfg, backend=backend, last_idx=last_idx)
+            params, batch["tokens"], state, start_len, chunk_len, table_rows,
+            aux["enc_table"], aux["enc_len"], cfg, backend=backend,
+            last_idx=last_idx)
     if cfg.family == "hybrid":
         return LM.hybrid_prefill_chunk(
-            params, batch["tokens"], cache, fixed, slots, start_len,
-            chunk_len, table_rows, cfg, backend=backend, last_idx=last_idx)
-    return LM.lm_prefill_chunk(params, batch["tokens"], cache, start_len,
+            params, batch["tokens"], state, start_len, chunk_len, table_rows,
+            aux["slots"], aux["active"], cfg, backend=backend,
+            last_idx=last_idx)
+    return LM.lm_prefill_chunk(params, batch["tokens"], state, start_len,
                                chunk_len, table_rows, cfg, backend=backend,
                                last_idx=last_idx, **_lm_kw(batch))
 
 
-def decode_paged_fn(params, batch, cache, table_rows, cfg: ModelConfig, *,
-                    backend: str = "auto", fixed=None, active=None,
-                    enc_table=None, enc_len=None):
-    """One decode step against paged pools; ``table_rows[B, P]`` maps each
-    slot's logical pages to pool pages.  The attention impl is picked by
-    ``cfg.paged_attn_impl`` (+ ``backend``): the fused Pallas page-gather
-    kernel on TPU / interpret, the jnp dense gather as the XLA reference.
-
-    Hybrid configs take/return the fixed-rows tree plus an ``active[B]``
-    mask (rows not decoding keep their SSM state) — a 3-tuple result;
-    enc-dec configs take the encoder page table + valid length."""
+def decode_paged_fn(params, batch, state, table_rows, cfg: ModelConfig,
+                    aux=None, *, backend: str = "auto"):
+    """One decode step against the paged state; ``table_rows[B, P]`` maps
+    each row's logical pages to pool pages, and ``cfg.paged_attn_impl``
+    (+ ``backend``) picks the attention impl: the fused Pallas kernel on
+    TPU / interpret, the jnp dense gather as the XLA reference.  ``aux`` is
+    as for :func:`prefill_chunk_fn`.  Returns ``(logits, state)``."""
     if cfg.encdec:
         return W.whisper_decode_paged(
-            params, batch["token"], cache, batch["position"], table_rows,
-            enc_table, enc_len, cfg, backend=backend)
+            params, batch["token"], state, batch["position"], table_rows,
+            aux["enc_table"], aux["enc_len"], cfg, backend=backend)
     if cfg.family == "hybrid":
         return LM.hybrid_decode_paged(
-            params, batch["token"], cache, fixed, batch["position"],
-            table_rows, active, cfg, backend=backend)
-    return LM.lm_decode_paged(params, batch["token"], cache, batch["position"],
+            params, batch["token"], state, batch["position"], table_rows,
+            aux["slots"], aux["active"], cfg, backend=backend)
+    return LM.lm_decode_paged(params, batch["token"], state, batch["position"],
                               table_rows, cfg, backend=backend)
 
 

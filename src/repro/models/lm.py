@@ -494,8 +494,8 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int) -> Any:
     """Per-layer paged KV pools (stacked over layers, shared across slots).
 
     Hybrid stacks page only the shared-attention applications (one pool
-    layer per group); their SSM state lives in the separate fixed-rows tree
-    from :func:`init_fixed_state`.  Enc-dec pools live in
+    layer per group); their SSM state rows from :func:`init_fixed_state`
+    join the pools in ``api.init_paged_state``.  Enc-dec pools live in
     ``models/whisper.py`` (dispatched by ``models/api.py``)."""
     ok, why = paged_supported(cfg)
     if not ok:
@@ -587,44 +587,55 @@ def lm_decode_paged(
     return logits, {"layers": nst}
 
 
-def _group_fixed(fixed, g, k):
-    """Split the [M, B, ...] fixed-state tree into grouped [g, k, B, ...] and
-    tail [tail, B, ...] trees (group-major layer order, tail last)."""
-    grouped = jax.tree.map(lambda a: a[: g * k].reshape(g, k, *a.shape[1:]), fixed)
-    tail_st = jax.tree.map(lambda a: a[g * k:], fixed)
+def _fixed_rows_in(fixed, slots, g, k):
+    """Gather the launch rows' fixed state (row ``b`` reads slot
+    ``slots[b]``) and split the ``[M, B, ...]`` tree into grouped
+    ``[g, k, B, ...]`` and tail ``[tail, B, ...]`` trees (group-major layer
+    order, tail last)."""
+    rows = jax.tree.map(lambda a: a[:, slots], fixed)
+    grouped = jax.tree.map(lambda a: a[: g * k].reshape(g, k, *a.shape[1:]),
+                           rows)
+    tail_st = jax.tree.map(lambda a: a[g * k:], rows)
     return grouped, tail_st
 
 
-def _ungroup_fixed(grouped, tail_st, tail):
-    flat = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), grouped)
+def _fixed_rows_out(fixed, slots, active, grouped, tail_st, tail):
+    """Inverse of :func:`_fixed_rows_in`: write the launch rows' new state
+    back to their slots.  Rows with ``active`` false keep their state: the
+    trash-page convention masks a parked row's KV write, but an SSM
+    recurrence would otherwise overwrite its slot's state."""
+    new = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), grouped)
     if tail:
-        flat = jax.tree.map(
-            lambda a, t_: jnp.concatenate([a, t_], axis=0), flat, tail_st)
-    return flat
+        new = jax.tree.map(
+            lambda a, t_: jnp.concatenate([a, t_], axis=0), new, tail_st)
+
+    def put(a, r):
+        keep = active.reshape((1, -1) + (1,) * (r.ndim - 2))
+        return a.at[:, slots].set(jnp.where(keep, r, a[:, slots]))
+    return jax.tree.map(put, fixed, new)
 
 
 def hybrid_decode_paged(
     p: Params,
     token: jax.Array,             # [B, 1] int32
-    cache: Any,                   # g shared-attn pools from init_paged_cache
-    fixed: Any,                   # [M, B, ...] tree from init_fixed_state
+    cache: Any,                   # from api.init_paged_state
     position: jax.Array,          # [B] int32 current position
     table_rows: jax.Array,        # [B, P] int32 page table
+    slots: jax.Array,             # [B] int32 slot of each row
     active: jax.Array,            # [B] bool: rows actually decoding
     cfg: ModelConfig,
     *,
     backend: str = "auto",
-) -> Tuple[jax.Array, Any, Any]:
-    """One hybrid decode step: mamba layers update their fixed state rows,
-    the weight-shared attention block hits one paged pool per group.  Rows
-    with ``active=False`` keep their fixed state untouched (the trash-page
-    convention masks their KV writes, but an SSM recurrence would otherwise
-    corrupt a parked slot's state).  Returns (logits, pools, fixed)."""
-    b = token.shape[0]
+) -> Tuple[jax.Array, Any]:
+    """One hybrid decode step: mamba layers update their slots' fixed state
+    rows (``cache["fixed"]``, ``[M, Bslots, ...]``), the weight-shared
+    attention block hits one paged pool per group (``cache["layers"]``).
+    Rows with ``active=False`` keep their fixed state untouched.  Returns
+    (logits, cache)."""
     pos = position[:, None]
     x = L.apply_embedding(p["embed"], token)
     g, k, tail = _hybrid_layout(cfg)
-    grouped, tail_st = _group_fixed(fixed, g, k)
+    grouped, tail_st = _fixed_rows_in(cache["fixed"], slots, g, k)
     shared = p["shared"]
     scfg = cfg.with_(moe=None)
 
@@ -646,40 +657,33 @@ def hybrid_decode_paged(
     ntail = tail_st
     if tail:
         x, ntail = jax.lax.scan(mamba_step, x, (p["tail"], tail_st))
-    new_fixed = _ungroup_fixed(ngst, ntail, tail)
-    new_fixed = jax.tree.map(
-        lambda new, old: jnp.where(
-            active.reshape((1, b) + (1,) * (new.ndim - 2)), new, old),
-        new_fixed, fixed)
+    fixed = _fixed_rows_out(cache["fixed"], slots, active, ngst, ntail, tail)
     logits = _lm_head(p, x, cfg, backend)[:, 0]
-    return logits, {"layers": npools}, new_fixed
+    return logits, {"layers": npools, "fixed": fixed}
 
 
 def hybrid_prefill_chunk(
     p: Params,
     tokens: jax.Array,            # [B, T] int32 chunk tokens (right-padded)
-    cache: Any,                   # g shared-attn pools
-    fixed: Any,                   # [M, Bslots, ...] full fixed-state tree
-    slots: jax.Array,             # [B] int32 slot ids of the bucket rows
+    cache: Any,                   # from api.init_paged_state
     start_len: jax.Array,         # [B] int32 tokens already processed
     chunk_len: jax.Array,         # [B] int32 valid rows of this chunk
     table_rows: jax.Array,        # [B, P] int32 page table
+    slots: jax.Array,             # [B] int32 slot of each row
+    active: jax.Array,            # [B] bool: rows actually prefilling
     cfg: ModelConfig,
     *,
     backend: str = "auto",
     last_idx=None,
-) -> Tuple[jax.Array, Any, Any]:
+) -> Tuple[jax.Array, Any]:
     """Chunked hybrid prefill: mamba layers run the chunked SSD with state-in
-    (``mamba2_prefill_chunk``), the shared attention block scatters KV into
-    its per-group pool.  Fixed rows are gathered at ``slots`` on the way in
-    and scattered back on the way out — every bucket row is an actively
-    prefilling slot, so the scatter is unconditional.
-    Returns (last-chunk-token logits, pools, fixed)."""
+    (``mamba2_prefill_chunk``) from their slots' fixed rows, the shared
+    attention block scatters KV into its per-group pool.  Returns
+    (last-chunk-token logits, cache)."""
     b, t = tokens.shape[:2]
     x = _embed_in(p, tokens, cfg, None)
     g, k, tail = _hybrid_layout(cfg)
-    fx = jax.tree.map(lambda a: a[:, slots], fixed)
-    grouped, tail_st = _group_fixed(fx, g, k)
+    grouped, tail_st = _fixed_rows_in(cache["fixed"], slots, g, k)
     shared = p["shared"]
     scfg = cfg.with_(moe=None)
 
@@ -703,12 +707,11 @@ def hybrid_prefill_chunk(
     ntail = tail_st
     if tail:
         x, ntail = jax.lax.scan(mamba_body, x, (p["tail"], tail_st))
-    new_fx = _ungroup_fixed(ngst, ntail, tail)
-    new_fixed = jax.tree.map(
-        lambda a, r: a.at[:, slots].set(r), fixed, new_fx)
+    fixed = _fixed_rows_out(cache["fixed"], slots, active, ngst, ntail, tail)
     idx = last_idx if last_idx is not None else jnp.full((b,), t - 1, jnp.int32)
     x_last = x[jnp.arange(b), idx][:, None]
-    return _lm_head(p, x_last, cfg, backend)[:, 0], {"layers": npools}, new_fixed
+    logits = _lm_head(p, x_last, cfg, backend)[:, 0]
+    return logits, {"layers": npools, "fixed": fixed}
 
 
 def lm_decode(

@@ -50,27 +50,32 @@ from a fixed-slot continuous batcher backed by a **paged KV cache**:
   cached pages stay resident as an LRU pool reserve and are evicted exactly
   when an allocation needs them.  Shared pages are never swapped out with a
   preemption victim — swap-in re-acquires them.  Cache-hit requests emit
-  greedy tokens identical to a cold run (asserted in tests/CI; note the
+  greedy tokens identical to a cold run (asserted in tests; note the
   identity is at the argmax level — a warm suffix prefill reads the prefix
   through the pools, so under ``kv_quant`` its logits match the cold run's
   only to within int8 quantization error, exactly like paged decode steps
   already do).
 
 **State leaves** (``api.state_leaves``): a slot's device state is one or
-more typed leaves, and every lifecycle primitive (admit, grow, preempt,
-swap, resume, free) handles each kind by its own invariants:
+more typed leaves of one state tree (``self.pools``, from
+``api.init_paged_state``).  Every family launches through the same two step
+programs, which take, donate and return that tree whole; the per-row inputs
+a leaf needs ride in one ``aux`` dict built by :meth:`_launch_rows`.  Every
+lifecycle primitive (admit, grow, preempt, swap, resume, free) handles each
+kind by its own invariants:
 
-- ``kv_pages`` — the paged attention pools above (every config has them;
-  hybrid configs page only their shared-attention applications);
-- ``fixed_rows`` — per-layer recurrent state rows ``[M, B, ...]`` for
-  hybrid SSM configs (zamba2): O(1) per slot, never paged, zeroed at
-  admission, round-tripped bit-exactly through the host swap buffer next
-  to the KV rows under one combined CRC-32;
-- ``shared_ro`` — read-only encoder K/V pages for enc-dec configs
-  (whisper): allocated once per request in the pager's ``"enc"`` page
-  group, deduplicated across requests by an exact-match (whole-sequence)
-  prefix-cache index, never host-swapped — preemption detaches them under
-  swap holds and resume reattaches.
+- ``kv_pages`` — the paged attention pools above, under ``"layers"`` (every
+  config has them; hybrid configs page only their shared-attention
+  applications);
+- ``fixed_rows`` — per-layer recurrent state rows ``[M, B, ...]`` under
+  ``"fixed"`` for hybrid SSM configs (zamba2): O(1) per slot, never paged,
+  zeroed at admission, round-tripped bit-exactly through the host swap
+  buffer next to the KV rows under one combined CRC-32;
+- ``shared_ro`` — read-only encoder K/V pages under ``"enc"`` for enc-dec
+  configs (whisper): allocated once per request in the pager's ``"enc"``
+  page group, deduplicated across requests by an exact-match
+  (whole-sequence) prefix-cache index, never host-swapped — preemption
+  detaches them under swap holds and resume reattaches.
 
 The *token* prefix cache stays attention-only: KV pages cannot capture an
 SSM boundary state and cross-attention depends on the encoder input, so
@@ -235,7 +240,6 @@ class ServingEngine:
         backend: str = "auto",
         seed: int = 0,
         max_prefill_tokens: Optional[int] = None,
-        prefill_mode: str = "bucketed",
         reservation: str = "lazy",
         prefix_cache: bool = False,
         max_queue: Optional[int] = None,
@@ -304,14 +308,15 @@ class ServingEngine:
         self.enc_cache: Optional[PrefixCache] = (
             PrefixCache(self.pager, page_size, mode="enc")
             if self.has_enc else None)
-        self.pools = api.init_paged_cache(cfg, num_pages, page_size)
-        self.fixed = (api.init_fixed_state(cfg, batch_size)
-                      if self.has_fixed else None)
+        # the one state tree (module docstring); swap moves its KV pages,
+        # under "layers" for every family, and hybrid's "fixed" rows
+        self.pools = api.init_paged_state(cfg, num_pages, page_size,
+                                          batch_size)
         self.enc_len = np.zeros(batch_size, np.int32)   # valid enc rows/slot
         self.reservation = reservation
         self.sched = Scheduler(page_size=page_size, max_seq=self.S,
                                max_prefill_tokens=max_prefill_tokens,
-                               mode=prefill_mode, reservation=reservation)
+                               reservation=reservation)
 
         self.slots: List[Optional[Request]] = [None] * batch_size
         self.pos = np.zeros(batch_size, np.int32)      # next position per slot
@@ -352,9 +357,10 @@ class ServingEngine:
         # All recording is host-side bookkeeping (ints, floats, deques) —
         # it never touches jax, the pools, or the RNG stream, which is what
         # makes the metrics-on/off greedy token-identity guarantee hold.
-        # ``metrics=False`` strips every observe from the hot path (the
-        # overhead-benchmark baseline); the registry still exists so
-        # metrics_snapshot() stays well-formed (empty histograms).
+        # ``metrics=False`` strips every observe from the hot path (what
+        # ``serve.py --no-metrics`` and the metrics on/off identity tests
+        # run); the registry still exists so metrics_snapshot() stays
+        # well-formed (empty histograms).
         self._obs = metrics
         self.metrics = MetricsRegistry(clock=lambda: self._clock())
         self.trace = TraceRecorder(lambda: self._clock(), enabled=metrics)
@@ -378,74 +384,44 @@ class ServingEngine:
             fault_plan.sink = self._on_fault
         self._last_dec: List[int] = []      # decode slots of the last step
 
-        # donate the pools: the step's output cache aliases the input buffers
-        # instead of allocating a second full pool every decoded token.
-        # The launch signature follows the config's state leaves — hybrid
-        # threads the fixed-rows tree (donated too) plus an active mask,
-        # enc-dec threads the encoder page table + valid lengths.  Each step
-        # program is a named function, so a device trace names its module
-        # (``jit_decode_step``, ``jit_prefill_chunk_step``).
-        if self.has_fixed:
-            def decode_step(p, c, fixed, tok, pos, table, active):
-                return api.decode_paged_fn(
-                    p, {"token": tok, "position": pos}, c, table, cfg,
-                    backend=backend, fixed=fixed, active=active)
-            self._decode = jax.jit(decode_step, donate_argnums=(1, 2))
-        elif self.has_enc:
-            def decode_step(p, c, tok, pos, table, enc_table, enc_len):
-                return api.decode_paged_fn(
-                    p, {"token": tok, "position": pos}, c, table, cfg,
-                    backend=backend, enc_table=enc_table, enc_len=enc_len)
+        # One launch signature for every family.  The whole state tree is
+        # donated, so a step's output aliases its input buffers instead of
+        # allocating a second pool every token.  ``aux`` is empty for
+        # attention-only state and otherwise one dict of the per-row inputs
+        # the other leaves need (:meth:`_launch_rows`); it is passed as
+        # ``*aux``, so an attention-only launch is positionally
+        # ``(p, pools, tok, pos, table)`` and never lowers a second program.
+        # Each step program is a named function, so a device trace names its
+        # module (``jit_decode_step``, ``jit_prefill_chunk_step``).
+        def decode_step(p, pools, tok, pos, table, *aux):
+            return api.decode_paged_fn(
+                p, {"token": tok, "position": pos}, pools, table, cfg, *aux,
+                backend=backend)
 
-            def encode_step(p, fr):
-                return api.encode_kv_fn(p, fr, cfg, backend=backend)
-            self._decode = jax.jit(decode_step, donate_argnums=(1,))
-            self._encode = jax.jit(encode_step)
-        else:
-            def decode_step(p, c, tok, pos, table):
-                return api.decode_paged_fn(
-                    p, {"token": tok, "position": pos}, c, table, cfg,
-                    backend=backend)
-            self._decode = jax.jit(decode_step, donate_argnums=(1,))
         # joint length-bucketed chunk prefill: each row is one [blen] prompt
         # chunk at logical positions start_len[r] + t; KV scatters into the
         # pages and attention reads every earlier token (cached prefix and
         # prior chunks alike) through the table.  jit re-specializes per
         # (n, bucket_len); the scheduler's power-of-two buckets keep that
-        # trace count O(log max_seq).  Pools donated: the chunk's output
-        # cache aliases the input buffers.
-        if self.has_fixed:
-            def prefill_chunk_step(p, toks, last_idx, starts, lens, table,
-                                   pools, fixed, slots):
-                return api.prefill_chunk_fn(
-                    p, {"tokens": toks}, pools, table, starts, lens, cfg,
-                    backend=backend, last_idx=last_idx, fixed=fixed,
-                    slots=slots)
+        # trace count O(log max_seq).
+        def prefill_chunk_step(p, toks, last_idx, starts, lens, table, pools,
+                               *aux):
+            return api.prefill_chunk_fn(
+                p, {"tokens": toks}, pools, table, starts, lens, cfg, *aux,
+                backend=backend, last_idx=last_idx)
 
-            # fresh admission starts from zero SSM state (the previous
-            # occupant's rows are stale, not trash-maskable like KV pages)
-            def fixed_zero(f, slot):
-                return jax.tree.map(lambda a: a.at[:, slot].set(0), f)
-            self._prefill_chunk = jax.jit(prefill_chunk_step,
-                                          donate_argnums=(6, 7))
-            self._fixed_zero = jax.jit(fixed_zero, donate_argnums=(0,))
-        elif self.has_enc:
-            def prefill_chunk_step(p, toks, last_idx, starts, lens, table,
-                                   pools, enc_table, enc_len):
-                return api.prefill_chunk_fn(
-                    p, {"tokens": toks}, pools, table, starts, lens, cfg,
-                    backend=backend, last_idx=last_idx,
-                    enc_table=enc_table, enc_len=enc_len)
-            self._prefill_chunk = jax.jit(prefill_chunk_step,
-                                          donate_argnums=(6,))
-        else:
-            def prefill_chunk_step(p, toks, last_idx, starts, lens, table,
-                                   pools):
-                return api.prefill_chunk_fn(
-                    p, {"tokens": toks}, pools, table, starts, lens, cfg,
-                    backend=backend, last_idx=last_idx)
-            self._prefill_chunk = jax.jit(prefill_chunk_step,
-                                          donate_argnums=(6,))
+        # admission programs of the non-KV leaves: the encoder pass of an
+        # enc-dec request, and zeroing a hybrid slot's stale recurrent state
+        def encode_step(p, fr):
+            return api.encode_kv_fn(p, fr, cfg, backend=backend)
+
+        def fixed_zero(f, slot):
+            return jax.tree.map(lambda a: a.at[:, slot].set(0), f)
+
+        self._decode = jax.jit(decode_step, donate_argnums=(1,))
+        self._prefill_chunk = jax.jit(prefill_chunk_step, donate_argnums=(6,))
+        self._encode = jax.jit(encode_step)
+        self._fixed_zero = jax.jit(fixed_zero, donate_argnums=(0,))
         self._sample = jax.jit(sample_per_slot)
 
     # ----------------------------------------------------- observability ---
@@ -653,19 +629,6 @@ class ServingEngine:
             hashes=hashes)
 
     # ---------------------------------------------------- swap-out / -in ---
-    def _kv_pools(self):
-        """The KV-pages subtree of the device pools — what page-granular
-        swap gathers/scatters.  Enc-dec pools also hold the read-only
-        encoder pool, which must never ride a KV swap image (its pages are
-        detached/reattached, the data never moves)."""
-        return self.pools["layers"] if self.has_enc else self.pools
-
-    def _set_kv_pools(self, new) -> None:
-        if self.has_enc:
-            self.pools = {**self.pools, "layers": new}
-        else:
-            self.pools = new
-
     def _preempt(self, slot: int) -> None:
         """Swap ``slot`` out and requeue its request at the queue *head* (it
         was admitted before anything still queued, so FCFS order is
@@ -690,7 +653,7 @@ class ServingEngine:
         rows, nbytes = None, 0
         if private:
             rows = api.gather_pool_rows(
-                self._kv_pools(),
+                self.pools["layers"],
                 jnp.asarray([p for _, p in private], jnp.int32))
             # start the device->host transfer without blocking the step loop
             jax.tree.map(lambda a: a.copy_to_host_async(), rows)
@@ -698,7 +661,7 @@ class ServingEngine:
         frows, fbytes = None, 0
         if self.has_fixed:
             frows = api.gather_pool_rows(
-                self.fixed, jnp.asarray([slot], jnp.int32))
+                self.pools["fixed"], jnp.asarray([slot], jnp.int32))
             jax.tree.map(lambda a: a.copy_to_host_async(), frows)
             fbytes = sum(a.nbytes for a in jax.tree.leaves(frows))
         enc_pages, enc_len = None, 0
@@ -737,14 +700,14 @@ class ServingEngine:
         fresh = self.pager.swap_in(slot, st.kept, st.private_lis)
         if st.rows is not None:
             rows = jax.device_get(st.rows)     # no-op once drained to host
-            self._set_kv_pools(api.scatter_pool_rows(
-                self._kv_pools(), rows, jnp.asarray(fresh, jnp.int32)))
+            self.pools = {**self.pools, "layers": api.scatter_pool_rows(
+                self.pools["layers"], rows, jnp.asarray(fresh, jnp.int32))}
         if st.fixed_rows is not None:
             # same dtypes both ways (f32 h, model-dtype conv tails), so the
             # restored recurrent state is bit-identical to the preempted one
-            self.fixed = api.scatter_pool_rows(
-                self.fixed, jax.device_get(st.fixed_rows),
-                jnp.asarray([slot], jnp.int32))
+            self.pools = {**self.pools, "fixed": api.scatter_pool_rows(
+                self.pools["fixed"], jax.device_get(st.fixed_rows),
+                jnp.asarray([slot], jnp.int32))}
         if st.enc_pages is not None:
             self.pager.reattach_group(slot, "enc", st.enc_pages)
             self.enc_len[slot] = st.enc_len
@@ -901,10 +864,10 @@ class ServingEngine:
             # until its rows are duplicated here (one batched dispatch).
             pairs = [p for p in bkt.cow if p is not None]
             if pairs:
-                self.pools = api.copy_pool_page(
-                    self.pools,
+                self.pools = {**self.pools, "layers": api.copy_pool_page(
+                    self.pools["layers"],
                     jnp.asarray([s for s, _ in pairs], jnp.int32),
-                    jnp.asarray([d for _, d in pairs], jnp.int32))
+                    jnp.asarray([d for _, d in pairs], jnp.int32))}
                 for src, _ in pairs:
                     self.pager.drop_hold(src)
                 self.stats.cow_copies += len(pairs)
@@ -920,8 +883,8 @@ class ServingEngine:
                 if self.has_fixed:
                     # the previous occupant's recurrent state is stale, not
                     # trash-maskable like KV pages — zero it before chunk 1
-                    self.fixed = self._fixed_zero(
-                        self.fixed, jnp.asarray(slot, jnp.int32))
+                    self.pools = {**self.pools, "fixed": self._fixed_zero(
+                        self.pools["fixed"], jnp.asarray(slot, jnp.int32))}
                 if self.has_enc:
                     self._admit_enc(slot, req)
                 self.stats.admitted += 1
@@ -1027,26 +990,15 @@ class ServingEngine:
                     req = self.slots[slot]
                     toks[r, : lens[r]] = \
                         req.prompt[starts[r]: starts[r] + lens[r]]
-                table = jnp.asarray(self.pager.table()[bkt.slots])
+                table, aux = self._launch_rows(bkt.slots, np.ones(n, bool))
                 args = (jnp.asarray(toks), jnp.asarray(lens - 1),
                         jnp.asarray(starts), jnp.asarray(lens), table)
-                if self.has_fixed:
-                    extra = (jnp.asarray(bkt.slots, jnp.int32),)
-                elif self.has_enc:
-                    extra = (jnp.asarray(self.pager.table("enc")[bkt.slots]),
-                             jnp.asarray(self.enc_len[list(bkt.slots)]))
-                else:
-                    extra = ()
             with phase("prefill_dispatch"):
-                if self.has_fixed:
-                    logits, self.pools, self.fixed = self._prefill_chunk(
-                        self.params, *args, self.pools, self.fixed, *extra)
-                else:
-                    logits, self.pools = self._prefill_chunk(
-                        self.params, *args, self.pools, *extra)
+                logits, self.pools = self._prefill_chunk(
+                    self.params, *args, self.pools, *aux)
                 # drop the chunk's uploads once the launch holds them, so
                 # they are not kept alive while the next bucket allocates
-                del args, extra
+                del args, aux
                 finals = [self.slots[s] if f else None
                           for s, f in zip(bkt.slots, bkt.final)]
                 if any(bkt.final):
@@ -1193,6 +1145,32 @@ class ServingEngine:
         with phase("decode"):
             return self._decode_slots(dec) + chunked
 
+    def _launch_rows(self, rows, live) -> Tuple[jax.Array, tuple]:
+        """The page table and ``aux`` of a step launch whose row ``r`` serves
+        slot ``rows[r]``.  Rows with ``live[r]`` false do work that lands
+        nowhere: their table rows point at the trash page, which absorbs the
+        dummy KV write; their encoder reads see the trash page with zero
+        valid rows (clamped to one masked row inside the model); and their
+        fixed rows keep their state (``active`` false).  ``aux`` is ``()``
+        for attention-only state and otherwise ``(dict,)``, so the launch
+        passes it as ``*aux``."""
+        rows = np.asarray(rows, np.int32)
+        dead = ~np.asarray(live, bool)
+        table = self.pager.table()[rows]
+        table[dead] = KV.TRASH_PAGE
+        aux = {}
+        if self.has_fixed:
+            aux["slots"] = jnp.asarray(rows)
+            aux["active"] = jnp.asarray(~dead)
+        if self.has_enc:
+            etbl = self.pager.table("enc")[rows]
+            elen = self.enc_len[rows]
+            etbl[dead] = KV.TRASH_PAGE
+            elen[dead] = 0
+            aux["enc_table"] = jnp.asarray(etbl)
+            aux["enc_len"] = jnp.asarray(elen)
+        return jnp.asarray(table), ((aux,) if aux else ())
+
     def _decode_slots(self, dec: List[int]) -> int:
         """Decode one token for every slot in ``dec``; returns ``len(dec)``
         (0 when an injected launch fault skips the step)."""
@@ -1205,47 +1183,21 @@ class ServingEngine:
                                "decode launch faulted")
             return 0
         with phase("decode_inputs"):
-            # mask mid-prefill rows out of the decode launch exactly like
-            # empty slots: trash-page table rows absorb the dummy KV write
-            # and the row's logits are discarded — so their real pages
-            # never see a stray write
-            dset = set(dec)
-            idle = [i for i in range(self.B) if i not in dset]
-            tbl_np = self.pager.table().copy()
+            # rows not decoding (empty, or mid-prefill) are masked out of the
+            # launch (:meth:`_launch_rows`) and their logits are discarded
+            live = np.zeros(self.B, bool)
+            live[dec] = True
             pos_np = self.pos.copy()
             tok_np = self.last_tok.copy()
-            tbl_np[idle] = KV.TRASH_PAGE
-            pos_np[idle] = 0
-            tok_np[idle] = 0
-            args = (jnp.asarray(tok_np[:, None]), jnp.asarray(pos_np),
-                    jnp.asarray(tbl_np))
-            if self.has_fixed:
-                # trash-masking covers the KV write but not the recurrence:
-                # an explicit active mask freezes non-decoding rows' fixed
-                # state
-                act = np.zeros(self.B, bool)
-                act[dec] = True
-                extra = (jnp.asarray(act),)
-            elif self.has_enc:
-                # non-decoding rows read the trash page's zero rows with a
-                # zero valid length (clamped to one masked row inside the
-                # model) — their logits are discarded like empty slots'
-                etbl = self.pager.table("enc").copy()
-                elen = self.enc_len.copy()
-                etbl[idle] = KV.TRASH_PAGE
-                elen[idle] = 0
-                extra = (jnp.asarray(etbl), jnp.asarray(elen))
-            else:
-                extra = ()
+            pos_np[~live] = 0
+            tok_np[~live] = 0
+            table, aux = self._launch_rows(np.arange(self.B), live)
+            args = (jnp.asarray(tok_np[:, None]), jnp.asarray(pos_np), table)
         with phase("decode_dispatch"):
-            if self.has_fixed:
-                logits, self.pools, self.fixed = self._decode(
-                    self.params, self.pools, self.fixed, *args, *extra)
-            else:
-                logits, self.pools = self._decode(
-                    self.params, self.pools, *args, *extra)
+            logits, self.pools = self._decode(
+                self.params, self.pools, *args, *aux)
             self.key, sk = jax.random.split(self.key)
-            rows = [self.slots[i] if i in dset else None
+            rows = [self.slots[i] if live[i] else None
                     for i in range(self.B)]
             temps = jnp.asarray([
                 r.temperature if r else 0.0 for r in rows
@@ -1362,10 +1314,10 @@ class ServingEngine:
         the documented percentile rule), cumulative :class:`EngineStats`,
         labeled counters/gauges, scheduler and pager counters, pager
         occupancy, and the live pending set (uid, phase, progress, remaining
-        deadline).  ``launch/serve.py`` stat lines, the stall/max_steps
-        diagnostics (via :func:`repro.serving.metrics.format_pending`), and
-        ``benchmarks/run.py`` all read from here; nothing formats engine
-        internals on its own anymore."""
+        deadline).  ``launch/serve.py`` stat lines,
+        ``scripts/trace_viewer.py`` and the stall/max_steps diagnostics (via
+        :func:`repro.serving.metrics.format_pending`) all read from here;
+        nothing formats engine internals on its own."""
         now = self._clock()
         pending = []
         for r in self.queue:

@@ -30,9 +30,9 @@ and report that bucket's **upper bound**, clamped into the observed
 - overflow observations (``> bounds[-1]``) report the observed max.
 
 :class:`HistSnap` (from ``Histogram.counts()``) supports subtraction, so a
-benchmark can diff two snapshots and compute percentiles **of just the
-observations in between** — this is how ``benchmarks/run.py`` derives
-per-wave TTFT/ITL from a warm engine without resetting it.
+caller can diff two snapshots and compute percentiles **of just the
+observations in between** — per-wave TTFT/ITL from a warm engine without
+resetting it.
 """
 from __future__ import annotations
 

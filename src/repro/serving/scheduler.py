@@ -45,9 +45,6 @@ token is always prefilled (the engine needs last-token logits to sample):
 when the whole prompt is cached (a page-aligned full match) the plan takes a
 **copy-on-write** of the final matched page and re-prefills just the last
 prompt token into the private copy.
-
-``mode="slotwise"`` degenerates to one request per bucket at its exact prompt
-length — the seed engine's prefill strategy — kept as the benchmark baseline.
 """
 from __future__ import annotations
 
@@ -102,9 +99,7 @@ class _AdmissionCost:
 class Scheduler:
     def __init__(self, *, page_size: int, max_seq: int,
                  max_prefill_tokens: Optional[int] = None,
-                 mode: str = "bucketed", reservation: str = "lazy"):
-        if mode not in ("bucketed", "slotwise"):
-            raise ValueError(f"unknown prefill mode {mode!r}")
+                 reservation: str = "lazy"):
         if reservation not in ("lazy", "worstcase"):
             raise ValueError(f"unknown page reservation {reservation!r}")
         if max_prefill_tokens is not None and max_prefill_tokens < 1:
@@ -113,7 +108,6 @@ class Scheduler:
         self.page_size = page_size
         self.max_seq = max_seq
         self.max_prefill_tokens = max_prefill_tokens
-        self.mode = mode
         self.reservation = reservation
         # True when the last plan() aborted an admission on an injected
         # transient fault (rolled back, request back at the queue head) —
@@ -221,8 +215,7 @@ class Scheduler:
             # ones this request is re-using
             if not pool.can_alloc(fresh + reserve + cost.pinned + cost.enc):
                 break                       # FCFS: head blocks the line
-            blen = (suffix if self.mode == "slotwise"
-                    else self.bucket_len(suffix))
+            blen = self.bucket_len(suffix)
             if budget is not None and spent and spent + blen > budget:
                 break                       # chunk the backlog across steps
             queue.popleft()
@@ -259,8 +252,7 @@ class Scheduler:
             if self.reservation == "lazy":
                 reserve += 1                # growth headroom for the new slot
             shared = len(matched) - (1 if full_match else 0)
-            key = (blen if self.mode == "bucketed" else (blen, slot),
-                   prefix > 0)
+            key = (blen, prefix > 0)
             bkt = buckets.get(key)
             if bkt is None:
                 bkt = buckets[key] = PrefillBucket(blen, [], [], [])
@@ -309,11 +301,10 @@ class Scheduler:
                 aligned = ((written + c) // self.page_size) * self.page_size
                 if aligned > written:
                     c = aligned - written
-            blen = c if self.mode == "slotwise" else self.bucket_len(c)
-            key = blen if self.mode == "bucketed" else (blen, slot)
-            bkt = buckets.get(key)
+            blen = self.bucket_len(c)
+            bkt = buckets.get(blen)
             if bkt is None:
-                bkt = buckets[key] = ChunkBucket(blen, [], [], [], [])
+                bkt = buckets[blen] = ChunkBucket(blen, [], [], [], [])
             bkt.slots.append(slot)
             bkt.starts.append(written)
             bkt.lens.append(c)

@@ -93,7 +93,7 @@ class StepRecord:
 class TraceRecorder:
     """Bounded recorder the engine writes through.  ``enabled=False`` turns
     every method into a no-op returning immediately — the metrics-off
-    engine configuration used by the overhead benchmark."""
+    engine configuration (``ServingEngine(metrics=False)``)."""
 
     def __init__(self, clock: Callable[[], float], *, enabled: bool = True,
                  journal_len: int = 2048, keep_finished: int = 1024):

@@ -365,6 +365,37 @@ def test_worstcase_reservation_mode_never_grows(setup):
     assert st.grown_pages == 0 and st.preemptions == 0
 
 
+def test_lazy_reservation_runs_more_at_once_at_half_pool(setup):
+    """At a pool of half the worst-case reservation, lazy growth with
+    preemption runs strictly more requests at once than reserving
+    ``prompt + max_tokens`` up front, with the same greedy outputs."""
+    cfg, params = setup
+    b, ps, max_tokens = 4, 4, 12
+    rng = np.random.default_rng(0)
+    lens = [int(rng.integers(2, 5)) for _ in range(8)]       # <= 1 page each
+    prompts = [rng.integers(2, cfg.vocab_size, n).astype(np.int32)
+               for n in lens]
+    max_seq = max(lens) + max_tokens
+    pages = -(-max_seq // ps)
+    num_pages = max(pages, b * pages // 2) + 1
+    peak, outs = {}, {}
+    for reservation in ("worstcase", "lazy"):
+        eng = ServingEngine(params, cfg, batch_size=b, max_seq=max_seq,
+                            page_size=ps, num_pages=num_pages, backend="xla",
+                            reservation=reservation)
+        reqs = [Request(uid=i, prompt=p.copy(), max_tokens=max_tokens)
+                for i, p in enumerate(prompts)]
+        for r in reqs:
+            eng.submit(r)
+        st = eng.run_until_drained()
+        assert st.completed == len(reqs)
+        eng.pager.check_invariants()
+        peak[reservation] = st.max_active
+        outs[reservation] = [r.output for r in reqs]
+    assert peak["lazy"] > peak["worstcase"], peak
+    assert outs["lazy"] == outs["worstcase"]
+
+
 # ------------------------------------------------------------ regressions ---
 def test_decode_cap_request_fills_all_positions(setup):
     """Regression (off-by-one): a request may write every one of the S cache

@@ -9,7 +9,8 @@ import pytest
 from repro.core import quantize as q
 from repro.kernels import ops
 from repro.kernels.ref import w4a16_grouped_ref
-from repro.kernels.w4a16_grouped import w4a16_grouped_matmul
+from repro.kernels.w4a16_grouped import (grouped_weight_bytes,
+                                         w4a16_grouped_matmul)
 
 
 def _mk(e, c, d, f, g, seed=0, dtype=jnp.float32):
@@ -42,6 +43,19 @@ def test_grouped_kernel_matches_oracle(e, c, d, f, g, dtype):
         rtol=2e-2 if dtype == jnp.bfloat16 else 1e-5,
         atol=2e-1 if dtype == jnp.bfloat16 else 1e-4,
     )
+
+
+def test_packed_expert_weights_hold_under_a_third_of_bf16():
+    """Packed int4 codes plus per-group scales and zeros of a stacked expert
+    weight hold less than 0.32 of its bf16 bytes, and
+    ``grouped_weight_bytes`` counts exactly what is stored."""
+    e, d, f, g = 4, 256, 256, 128
+    _, qt = _mk(e, 8, d, f, g)
+    stored = sum(a.nbytes for a in jax.tree.leaves(qt))
+    packed, bf16 = grouped_weight_bytes(
+        e, d, f, g, scale_bytes=qt.scales.dtype.itemsize)
+    assert stored == packed
+    assert stored / bf16 < 0.32
 
 
 def test_grouped_kernel_ragged_capacity_rows_are_zero():

@@ -139,13 +139,22 @@ def test_a8_flag_is_static_metadata():
 
 
 # ------------------------------------------- eligibility + artifact flags ----
+def outlier_model(arch: str, seed: int = 0, hot_scale: float = 100.0):
+    """A float32 smoke model whose embedding scales a few channels by
+    ``hot_scale``: activation-outlier channels like those of models past
+    6.7B parameters, which SmoothQuant+ exists for, at smoke size."""
+    cfg = get_config(arch, smoke=True).with_(dtype="float32")
+    params = api.init_model(jax.random.PRNGKey(seed), cfg)
+    rng = np.random.default_rng(seed)
+    hot = np.ones(cfg.d_model, np.float32)
+    hot[rng.choice(cfg.d_model, size=max(2, cfg.d_model // 32),
+                   replace=False)] = hot_scale
+    params["embed"]["table"] = params["embed"]["table"] * hot[None, :]
+    return cfg, params
+
+
 @pytest.fixture(scope="module")
 def outlier_ptq():
-    import sys
-    from pathlib import Path
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-    from benchmarks.common import outlier_model
-
     cfg, params = outlier_model("codellama-7b")
     batches = C.synthetic_calibration_set(cfg, n_seqs=2, seq_len=24)
     qcfg = QuantConfig(group_size=16, alpha=0.5)
