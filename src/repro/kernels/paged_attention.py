@@ -5,24 +5,36 @@ The jnp paged decode path materializes ``gather_pages(pool, table)`` as a
 dense ``[B, P*PS, ...]`` array in HBM every step — the pool rows are read,
 written back out as the gathered copy, then read *again* by the attention
 einsum: ~3× the KV bytes of a single streaming pass, plus an O(batch ×
-max-pages) allocation on the memory-bound decode hot path.  This kernel
-indexes the pool *inside* the grid instead: the block table rides in as a
-scalar-prefetch operand (``pltpu.PrefetchScalarGridSpec``), and the K/V
-BlockSpec index maps read it to pick the pool page each ``(batch, page)``
-grid cell DMAs into VMEM.  No intermediate gather ever exists in HBM, and
-pages past a sequence's valid length are clamped to the previous block index
-so the pipeline elides their copies — bytes moved scale with *live tokens*,
-not ``batch × max_pages``.
+max-pages) allocation on the memory-bound decode hot path.  These kernels
+index the pool *inside* the kernel instead: the block table rides in as a
+scalar-prefetch operand (``pltpu.PrefetchScalarGridSpec``) and picks the
+pool pages that are DMA'd into VMEM.  No intermediate gather ever exists in
+HBM, and only pages that hold valid rows are copied — bytes moved scale with
+*live tokens*, not ``batch × max_pages``.
 
 Pool layout (``models/attention.py`` owns it): every page leaf is
 ``[NP, H, PS, D]`` (``H = Hkv`` for GQA, ``1`` for MLA latents) and every
-int8 scale leaf ``[NP, H, PS]``.  One grid step DMAs one whole page — all
-its heads, one contiguous block — whose trailing block dims are ``(PS, D)``
-and ``(H, PS)``: full extents or tile multiples, as the TPU lowering needs.
+int8 scale leaf ``[NP, H, PS]``.  A page's DMA moves all its heads, one
+contiguous block whose trailing dims are ``(PS, D)`` and ``(H, PS)``: full
+extents or tile multiples, as the TPU lowering needs.
 
 Grids
-  GQA decode: ``(B, P)``, pages innermost; each step attends every KV
-  head's ``grp = H/Hkv`` query heads against one page.
+  GQA decode: ``(B,)``, one step per batch row, whose loop walks only the
+  row's live pages: ``cdiv(cdiv(lengths[b], PS), ppb)`` blocks (at least
+  one) of ``ppb`` pages (:func:`_pages_per_block`: 128 rows a block, capped
+  by the table width and a VMEM budget).  The K/V pools stay in HBM
+  (``pl.ANY``); each block's live pages are copied by
+  ``pltpu.make_async_copy``, one DMA per page and leaf, into one of two VMEM
+  buffers, so block ``j+1``'s copies (or, from a row's last block, the next
+  row's first) fly while block ``j`` computes.  A partial last block copies
+  only its live pages; its other slots hold stale (finite) rows that the
+  ``pos < length`` mask drops.  Each KV head's ``grp`` query rows score
+  the whole block as one ``[grp, ppb·PS]`` tile.  Int8 scale leaves cannot
+  be sliced per page by a DMA (an HBM minor axis of ``PS`` lanes does not
+  tile), so each row's scales arrive gathered as ``[Hkv, ppb·cdiv(P, ppb)·PS]``
+  rows, one BlockSpec block per grid step.  Pools whose head dim is not
+  whole 128-lane tiles cannot be sliced by such a DMA and keep the grid
+  ``(B, P)``, one page a step through the BlockSpec pipeline.
   MLA decode (absorbed form): ``(B, P)``; scores run in the latent space
   (``q_lat·ckv + q_pe·kpe``) so the per-page work covers all H heads.
   Chunked prefill: ``(B, R/bq, P+1)`` — the chunk's ``R = T·grp`` (GQA) or
@@ -34,7 +46,9 @@ Grids
   flushes.
 
 Online-softmax state (m, l, acc) lives in VMEM scratch, one slice per KV
-head, initialized at page 0 and flushed on the last page step.
+head, initialized at a row's first page (block) and flushed after its last.
+The MLA decode and both prefill grids step one page at a time; their dead
+pages re-map to the last live page, so the pipeline elides the copy.
 
 Int8 pools: when scale operands are passed, K/V pages are int8 with per-row
 (head, position) f32 scales.  Scores are computed on the raw int8 codes
@@ -46,8 +60,9 @@ Scalar-prefetch contract (shared with ``serving/kv_cache.py``):
                   holding logical page ``p`` of batch row ``b`` (freed /
                   unused entries point at the trash page 0);
   ``lengths[B]``  valid rows per batch row, *including* the token written
-                  this step (``write_pos + 1``); clamps both the in-page
-                  validity mask and the dead-page DMA elision.
+                  this step (``write_pos + 1``); sets the in-page validity
+                  mask and which pages are copied — in GQA decode also the
+                  row's block count, the loop's trip count.
 """
 from __future__ import annotations
 
@@ -62,6 +77,12 @@ NEG_INF = -1e30
 # query rows per chunked-prefill grid step: bounds the VMEM held by the q
 # block, the f32 accumulator and the score tile for long chunks
 MAX_Q_ROWS = 512
+# pool rows per block of the GQA decode walk: one 128-row MXU tile of keys
+BLOCK_TOKENS = 128
+# TPU lane width: a hand-written DMA slices an HBM array in whole lane tiles
+LANES = 128
+# VMEM for the GQA decode walk's two buffered blocks of K and V pages
+KV_BLOCK_VMEM = 4 << 20
 
 
 def _live_pages(length, page_size):
@@ -151,8 +172,176 @@ def _page_maps(pages: int, ps: int, prefill: bool):
 
 
 # ============================================================== GQA decode ==
-def _gqa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                page_size: int, n_pages: int, scale: float, quant: bool):
+def _pages_per_block(pages: int, page_size: int, page_bytes: int) -> int:
+    """Pool pages the GQA decode walk copies per block: enough for
+    :data:`BLOCK_TOKENS` rows, capped by the table width and by
+    :data:`KV_BLOCK_VMEM` for two buffered blocks of ``page_bytes`` each
+    page."""
+    ppb = min(pages, -(-BLOCK_TOKENS // page_size))
+    return max(1, min(ppb, KV_BLOCK_VMEM // (2 * page_bytes)))
+
+
+def _gqa_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest, page_size: int,
+                n_pages: int, ppb: int, scale: float, quant: bool):
+    if quant:
+        ks_ref, vs_ref, o_ref, k_buf, v_buf, sem, slot_ref, m_ref, l_ref, \
+            acc_ref = rest
+    else:
+        o_ref, k_buf, v_buf, sem, slot_ref, m_ref, l_ref, acc_ref = rest
+    b = pl.program_id(0)
+    rows = pl.num_programs(0)
+    blk_rows = ppb * page_size
+
+    def copies(row, blk, slot, op):
+        """Start (or wait for) block ``blk`` of ``row``: one DMA per live
+        page and leaf, all signalling the slot's semaphore."""
+        live = _live_pages(len_ref[row], page_size)
+        for j in range(ppb):
+            @pl.when(blk * ppb + j < live)
+            def _page():
+                page = tbl_ref[row * n_pages + blk * ppb + j]
+                for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                    getattr(pltpu.make_async_copy(
+                        pool.at[page], buf.at[slot, j], sem.at[slot]), op)()
+
+    @pl.when(b == 0)
+    def _first():
+        # a partial block leaves its dead pages' buffer slots unwritten: keep
+        # them finite, as masked probabilities (0) still meet their values
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        copies(0, 0, 0, "start")
+
+    _init_state(m_ref, l_ref, acc_ref)
+    length = len_ref[b]
+    # >= 1 block, so every row has a first block to prefetch
+    last = jnp.maximum(pl.cdiv(_live_pages(length, page_size), ppb), 1) - 1
+
+    def block(i, slot):
+        nxt = 1 - slot
+
+        @pl.when(i < last)
+        def _next_block():
+            copies(b, i + 1, nxt, "start")
+
+        @pl.when((i == last) & (b + 1 < rows))
+        def _next_row():
+            copies(b + 1, 0, nxt, "start")
+
+        copies(b, i, slot, "wait")
+        start = pl.multiple_of(i * blk_rows, blk_rows)
+        pos = start + jax.lax.broadcasted_iota(
+            jnp.int32, (q_ref.shape[2], blk_rows), 1)
+        for h in range(q_ref.shape[1]):
+            # [ppb, PS, D] -> [ppb·PS, D]: one score tile for the block
+            k = k_buf[slot, :, h].reshape(blk_rows, -1)
+            v = v_buf[slot, :, h].reshape(blk_rows, -1)
+            s = _scores(q_ref[0, h], k) * scale             # [grp, ppb·PS]
+            if quant:
+                # int8 codes hit the MXU; the per-row scale lands on the
+                # score row — mirrors the jnp int8 reference exactly
+                s = s * ks_ref[0, h:h + 1, pl.ds(start, blk_rows)]
+            _softmax_step(m_ref, l_ref, acc_ref, h, s, pos < length, v,
+                          vs_ref[0, h:h + 1, pl.ds(start, blk_rows)]
+                          if quant else None)
+        return nxt
+
+    slot_ref[0] = jax.lax.fori_loop(0, last + 1, block, slot_ref[0])
+    _flush(o_ref, l_ref, acc_ref)
+
+
+def _scale_rows(scale, table_rows, width):
+    """Row-major scale rows ``[B, Hkv, width·PS]`` of each row's pages, the
+    table padded with trash pages to ``width`` entries."""
+    b, pages = table_rows.shape
+    tbl = jnp.pad(table_rows, ((0, 0), (0, width - pages)))
+    rows = scale[tbl]                                   # [B, width, Hkv, PS]
+    return rows.transpose(0, 2, 1, 3).reshape(b, scale.shape[1], -1)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def gqa_paged_attention(
+    q: jax.Array,               # [B, Hkv, grp, Dh] one decode token
+    k_pool: jax.Array,          # [NP, Hkv, PS, Dh] (bf16/f32 or int8)
+    v_pool: jax.Array,          # [NP, Hkv, PS, Dv]
+    table_rows: jax.Array,      # [B, P] int32 pool page per logical page
+    lengths: jax.Array,         # [B] int32 valid rows incl. this step's token
+    k_scale: jax.Array | None = None,   # [NP, Hkv, PS] f32 (int8 pools)
+    v_scale: jax.Array | None = None,
+    *,
+    sm_scale: float,
+    interpret: bool = False,
+) -> jax.Array:                 # [B, Hkv, grp, Dv] f32
+    """GQA decode attention over the paged pools: the block walk, or the
+    page grid where the TPU cannot DMA a page by hand — Mosaic slices no
+    page out of an HBM array whose minor axis is not whole 128-lane tiles,
+    so head dims such as 64 or 112 (Whisper, Zamba2) keep one grid step
+    per page."""
+    fn = (_gqa_block_walk
+          if interpret or (q.shape[-1] % LANES == 0
+                           and v_pool.shape[-1] % LANES == 0)
+          else _gqa_page_grid)
+    return fn(q, k_pool, v_pool, table_rows, lengths, k_scale, v_scale,
+              sm_scale=sm_scale, interpret=interpret)
+
+
+def _gqa_block_walk(q, k_pool, v_pool, table_rows, lengths, k_scale,
+                    v_scale, *, sm_scale, interpret):
+    b, hkv, grp, dh = q.shape
+    ps = k_pool.shape[2]
+    dv = v_pool.shape[-1]
+    pages = table_rows.shape[1]
+    quant = k_scale is not None
+    ppb = _pages_per_block(pages, ps, hkv * ps * (
+        dh * k_pool.dtype.itemsize + dv * v_pool.dtype.itemsize))
+
+    def fixed(bi, tbl, lens):
+        return (bi, 0, 0, 0)
+
+    in_specs = [pl.BlockSpec((1, hkv, grp, dh), fixed),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [q, k_pool, v_pool]
+    if quant:
+        # a page's [Hkv, PS] scales are too narrow a slice for a DMA (HBM
+        # minor dims tile by 128 lanes), so each row's scales come gathered
+        width = pl.cdiv(pages, ppb) * ppb
+        in_specs += [pl.BlockSpec((1, hkv, width * ps),
+                                  lambda bi, tbl, lens: (bi, 0, 0))] * 2
+        operands += [_scale_rows(k_scale, table_rows, width),
+                     _scale_rows(v_scale, table_rows, width)]
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, hkv, grp, dv), fixed),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, *k_pool.shape[1:]), k_pool.dtype),
+            pltpu.VMEM((2, ppb, *v_pool.shape[1:]), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ] + _state_scratch(hkv, grp, dv),
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _gqa_kernel, page_size=ps, n_pages=pages, ppb=ppb, scale=sm_scale,
+            quant=quant,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, grp, dv), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            # a row's last block prefetches the next row's first
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+    )(table_rows.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
+      *operands)
+
+
+def _gqa_page_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+                     page_size: int, n_pages: int, scale: float, quant: bool):
     if quant:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -172,8 +361,6 @@ def _gqa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         for h in range(q_ref.shape[1]):
             s = _scores(q_ref[0, h], k_ref[0, h]) * scale     # [grp, PS]
             if quant:
-                # int8 codes hit the MXU; the per-row scale lands on the
-                # (tiny) score row — mirrors the jnp int8 reference exactly
                 s = s * ks_ref[0, h:h + 1, :]
             pos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             _softmax_step(m_ref, l_ref, acc_ref, h, s, pos < length,
@@ -185,19 +372,10 @@ def _gqa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         _flush(o_ref, l_ref, acc_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
-def gqa_paged_attention(
-    q: jax.Array,               # [B, Hkv, grp, Dh] one decode token
-    k_pool: jax.Array,          # [NP, Hkv, PS, Dh] (bf16/f32 or int8)
-    v_pool: jax.Array,          # [NP, Hkv, PS, Dv]
-    table_rows: jax.Array,      # [B, P] int32 pool page per logical page
-    lengths: jax.Array,         # [B] int32 valid rows incl. this step's token
-    k_scale: jax.Array | None = None,   # [NP, Hkv, PS] f32 (int8 pools)
-    v_scale: jax.Array | None = None,
-    *,
-    sm_scale: float,
-    interpret: bool = False,
-) -> jax.Array:                 # [B, Hkv, grp, Dv] f32
+def _gqa_page_grid(q, k_pool, v_pool, table_rows, lengths, k_scale, v_scale,
+                   *, sm_scale, interpret):
+    """Grid ``(B, P)``, one pool page a step through the BlockSpec
+    pipeline; dead pages re-map to the last live one, eliding their DMA."""
     b, hkv, grp, dh = q.shape
     ps = k_pool.shape[2]
     dv = v_pool.shape[-1]
@@ -227,7 +405,7 @@ def gqa_paged_attention(
     )
     return pl.pallas_call(
         functools.partial(
-            _gqa_kernel, page_size=ps, n_pages=pages, scale=sm_scale,
+            _gqa_page_kernel, page_size=ps, n_pages=pages, scale=sm_scale,
             quant=quant,
         ),
         grid_spec=grid_spec,
@@ -574,29 +752,3 @@ def mla_paged_prefill(
     )(table_rows.reshape(-1).astype(jnp.int32), prefix_len.astype(jnp.int32),
       chunk_len.astype(jnp.int32), *operands)
     return out.reshape(b, t, h, r)
-
-
-# ====================================================== roofline estimates ==
-def paged_kv_bytes_per_step(lengths, pages_per_slot: int, page_size: int,
-                            row_bytes: int, impl: str) -> int:
-    """Analytic KV bytes one decode step moves through HBM, per layer.
-
-    ``row_bytes`` is the byte cost of one token row across every pool leaf
-    (K+V, or ckv+kpe, plus scale rows for int8 pools).
-
-    - ``"gather"``: the jnp path reads the full trash-padded table
-      (``B × P × PS`` rows), writes the dense gathered copy, and re-reads it
-      in the attention contraction → 3× full-table traffic, independent of
-      how many rows are actually live.
-    - ``"pallas"``: one streaming pass over live pages only
-      (``Σ_b ceil(len_b / PS) × PS`` rows); dead-page DMAs are elided by the
-      block-index clamp.
-    """
-    import numpy as np
-    lengths = np.asarray(lengths)
-    if impl == "gather":
-        return int(3 * lengths.shape[0] * pages_per_slot * page_size * row_bytes)
-    if impl == "pallas":
-        live = -(-lengths // page_size) * page_size
-        return int(live.sum() * row_bytes)
-    raise ValueError(f"unknown impl {impl!r}")

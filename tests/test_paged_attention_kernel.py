@@ -1,13 +1,15 @@
 """Pallas paged-attention decode kernel (interpret mode) vs the jnp
 dense-gather reference: GQA and MLA, fp16 and int8 pools, ragged lengths,
-partial last pages, batch > 1; plus the int8-pool engine end-to-end and the
-stale-page-table guard."""
+partial last pages and blocks, rows on the trash page, batch > 1; the GQA
+page grid against the block walk; plus the int8-pool engine end-to-end and
+the stale-page-table guard."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.kernels import paged_attention as PA
 from repro.models import api
 from repro.models import attention as A
 from repro.serving import kv_cache as KV
@@ -16,12 +18,14 @@ from repro.serving.engine import Request, ServingEngine
 ATOL = 1e-2  # bf16 activations; fp32 checks below are much tighter in practice
 
 
-def _paged_state(batch, pages_per_slot, page_size, seed=0):
-    """Pager + table with every slot allocated, trash page garbage included."""
+def _paged_state(batch, pages_per_slot, page_size, dead=()):
+    """Pager + table with every slot allocated but the ``dead`` ones, whose
+    rows point at the trash page, as idle slots' rows do."""
     pool_host = KV.PagePool(1 + batch * pages_per_slot, page_size, batch,
                             pages_per_slot)
     for s in range(batch):
-        pool_host.alloc(s, pages_per_slot)
+        if s not in dead:
+            pool_host.alloc(s, pages_per_slot)
     return pool_host, jnp.asarray(pool_host.table())
 
 
@@ -42,16 +46,40 @@ def _fill(pool, seed):
 # ragged: mid-page, page boundary - 1, full table - 1 (partial/full last page)
 WRITE_POS = [4, 15, 23]
 
+# GQA decode tables: (page size, pages per slot, KV heads, write positions,
+# dead rows).  "smoke" is narrower than one block (ppb falls to the table
+# width, 3), one query head per KV head; "blocks" puts lengths (write_pos +
+# 1) at 1, PS-1, PS, ppb·PS-1, ppb·PS, ppb·PS+1 and the full table, on 11
+# pages of 16: 8-page blocks, and a width that is not a multiple of them;
+# "dead_rows" mixes rows on the trash page (length 1) among live ones, as
+# idle slots are.  Both of those share each KV head among two query heads.
+GQA_TABLES = {
+    "smoke": (8, 3, 4, WRITE_POS, ()),
+    "blocks": (16, 11, 2, [0, 14, 15, 126, 127, 128, 175], ()),
+    "dead_rows": (16, 11, 2, [0, 127, 0, 175, 0, 40, 0], (0, 2, 4, 6)),
+}
 
-@pytest.mark.parametrize("kv_quant", [False, True])
-def test_gqa_paged_kernel_matches_gather(kv_quant):
-    cfg = get_config("codellama-7b", smoke=True).with_(kv_quant=kv_quant)
-    b, ps, pages = len(WRITE_POS), 8, 3
+
+def _gqa_case(table_case, kv_quant):
+    ps, pages, kv_heads, write_pos, dead = GQA_TABLES[table_case]
+    cfg = get_config("codellama-7b", smoke=True).with_(
+        kv_quant=kv_quant, num_kv_heads=kv_heads)
+    b = len(write_pos)
     p = A.init_gqa(jax.random.PRNGKey(0), cfg)
-    _, table = _paged_state(b, pages, ps)
+    _, table = _paged_state(b, pages, ps, dead)
     pool = _fill(A.init_gqa_page_pool(cfg, 1 + b * pages, ps), seed=1)
     x = jax.random.normal(jax.random.PRNGKey(2), (b, 1, cfg.d_model), cfg.jdtype)
-    wp = jnp.asarray(WRITE_POS)
+    return cfg, p, table, pool, x, jnp.asarray(write_pos)
+
+
+@pytest.mark.parametrize("table_case,kv_quant", [
+    pytest.param("smoke", False, id="False"),
+    pytest.param("smoke", True, id="True"),
+    *(pytest.param(c, q, id=f"{c}-{q}")
+      for c in ("blocks", "dead_rows") for q in (False, True)),
+])
+def test_gqa_paged_kernel_matches_gather(table_case, kv_quant):
+    cfg, p, table, pool, x, wp = _gqa_case(table_case, kv_quant)
     y_ref, pool_ref = A.gqa_decode_paged(
         p, x, wp[:, None], pool, table, wp,
         cfg.with_(paged_attn_impl="gather"), backend="xla")
@@ -89,23 +117,59 @@ def test_mla_paged_kernel_matches_gather(kv_quant):
 
 def test_gqa_kernel_ignores_trash_page_garbage():
     """Rows past each sequence's length live on dead/trash pages; poisoning
-    them with huge values must not leak into the kernel output."""
-    cfg = get_config("codellama-7b", smoke=True)
-    b, ps, pages = len(WRITE_POS), 8, 3
-    p = A.init_gqa(jax.random.PRNGKey(0), cfg)
-    _, table = _paged_state(b, pages, ps)
-    pool = _fill(A.init_gqa_page_pool(cfg, 1 + b * pages, ps), seed=1)
-    x = jax.random.normal(jax.random.PRNGKey(2), (b, 1, cfg.d_model), cfg.jdtype)
-    wp = jnp.asarray(WRITE_POS)
-    impl = cfg.with_(paged_attn_impl="pallas_interpret")
-    y0, _ = A.gqa_decode_paged(p, x, wp[:, None], pool, table, wp, impl,
-                               backend="xla")
-    poisoned = dict(pool, k=pool["k"].at[KV.TRASH_PAGE].set(1e4),
-                    v=pool["v"].at[KV.TRASH_PAGE].set(1e4))
-    y1, _ = A.gqa_decode_paged(p, x, wp[:, None], poisoned, table, wp, impl,
-                               backend="xla")
-    np.testing.assert_array_equal(np.asarray(y0, np.float32),
-                                  np.asarray(y1, np.float32))
+    them with huge values must not leak into the live rows' output, neither
+    on a one-block table nor through the block buffers that dead rows (on
+    the trash page) share with live rows whose last block is partial."""
+    for table_case in ("smoke", "dead_rows"):
+        cfg, p, table, pool, x, wp = _gqa_case(table_case, False)
+        live = [r for r in range(len(wp))
+                if r not in GQA_TABLES[table_case][-1]]
+        impl = cfg.with_(paged_attn_impl="pallas_interpret")
+        y0, _ = A.gqa_decode_paged(p, x, wp[:, None], pool, table, wp, impl,
+                                   backend="xla")
+        poisoned = dict(pool, k=pool["k"].at[KV.TRASH_PAGE].set(1e4),
+                        v=pool["v"].at[KV.TRASH_PAGE].set(1e4))
+        y1, _ = A.gqa_decode_paged(p, x, wp[:, None], poisoned, table, wp,
+                                   impl, backend="xla")
+        np.testing.assert_array_equal(np.asarray(y0, np.float32)[live],
+                                      np.asarray(y1, np.float32)[live])
+
+
+@pytest.mark.parametrize("kv_quant", [False, True])
+@pytest.mark.parametrize("table_case", ["smoke", "dead_rows"])
+def test_gqa_page_grid_matches_block_walk(table_case, kv_quant):
+    """The page grid, which the TPU runs where a page cannot be DMA'd by hand
+    (head dims off the 128-lane tile), agrees with the block walk."""
+    ps, pages, _, write_pos, dead = GQA_TABLES[table_case]
+    b, hkv, grp, d = len(write_pos), 2, 3, 32
+    _, table = _paged_state(b, pages, ps, dead)
+    pool = _fill(A.init_page_pool({"k": d, "v": d}, 1 + b * pages, hkv, ps,
+                                  jnp.bfloat16, kv_quant), seed=1)
+    q = jax.random.normal(jax.random.PRNGKey(2), (b, hkv, grp, d),
+                          jnp.bfloat16)
+    args = (q, pool["k"], pool["v"], table, jnp.asarray(write_pos) + 1,
+            pool.get("k_s"), pool.get("v_s"))
+    grid = PA._gqa_page_grid(*args, sm_scale=d ** -0.5, interpret=True)
+    walk = PA._gqa_block_walk(*args, sm_scale=d ** -0.5, interpret=True)
+    np.testing.assert_allclose(np.asarray(grid), np.asarray(walk),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("pages,ps,hkv,itemsize,want", [
+    (320, 16, 8, 2, 8),     # Mistral-Large, bf16: 128 rows, 256 KB a K block
+    (128, 16, 4, 2, 8),     # StarCoder2-15B, bf16
+    (128, 16, 4, 1, 8),     # int8 pools
+    (3, 8, 2, 2, 3),        # smoke widths: capped by the table width
+    (64, 256, 8, 2, 1),     # pages of 256 rows: one page already fills it
+    (320, 16, 64, 4, 2),    # 1 MiB pages of K+V: capped by the VMEM budget
+])
+def test_gqa_pages_per_block(pages, ps, hkv, itemsize, want):
+    """Pages per block follow the shapes: >= 128 rows a block, at most the
+    table's width, and two buffered blocks within the VMEM budget."""
+    page_bytes = hkv * ps * 2 * 128 * itemsize
+    ppb = PA._pages_per_block(pages, ps, page_bytes)
+    assert ppb == want
+    assert 2 * ppb * page_bytes <= PA.KV_BLOCK_VMEM or ppb == 1
 
 
 def test_int8_pool_shapes_and_prefix_quantization():

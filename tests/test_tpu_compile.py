@@ -105,13 +105,27 @@ def _pool(sds, heads, dim, quant):
     return data, scale
 
 
-@pytest.mark.parametrize("quant", [False, True])
-def test_gqa_paged_decode(sds, quant):
-    k, ks = _pool(sds, HKV, DH, quant)
+@pytest.mark.parametrize("quant,b,pages,hkv,grp,dh", [
+    pytest.param(False, B, PAGES, HKV, HQ // HKV, DH, id="False"),
+    pytest.param(True, B, PAGES, HKV, HQ // HKV, DH, id="True"),
+    # the cells' decode launches, each on its worst-case pool (block walk):
+    # sc2-code-sat (StarCoder2-15B, batch 64, max_seq 2048) and mistral-chat
+    # (Mistral-Large, 8 KV heads, batch 32, max_seq 5120); then head dims
+    # that are not whole lane tiles (page grid): Whisper-medium, Zamba2-7B
+    *(pytest.param(q, b, p, h, g, d, id=f"{name}-{q}") for q in (False, True)
+      for name, b, p, h, g, d in (("sc2-code-sat", 64, 128, HKV, 12, DH),
+                                  ("mistral-chat", 32, 320, 8, 12, DH),
+                                  ("whisper-medium", 8, 28, 16, 1, 64),
+                                  ("zamba2-7b", 8, 64, 32, 1, 112))),
+])
+def test_gqa_paged_decode(sds, quant, b, pages, hkv, grp, dh):
+    n_pages = 1 + b * pages
+    k = sds((n_pages, hkv, PS, dh), I8 if quant else BF16)
+    ks = (sds((n_pages, hkv, PS), F32),) if quant else ()
     _compile(lambda q, k, v, t, n, *s: PA.gqa_paged_attention(
-                 q, k, v, t, n, *s, sm_scale=DH ** -0.5),
-             sds((B, HKV, HQ // HKV, DH), BF16), k, k,
-             sds((B, PAGES), I32), sds((B,), I32), *ks, *ks)
+                 q, k, v, t, n, *s, sm_scale=dh ** -0.5),
+             sds((b, hkv, grp, dh), BF16), k, k,
+             sds((b, pages), I32), sds((b,), I32), *ks, *ks)
 
 
 @pytest.mark.parametrize("quant", [False, True])
